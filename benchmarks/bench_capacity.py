@@ -236,8 +236,6 @@ def bytes_per_tick(tag: str, network: bool = False,
     dyn = DynParams.from_params(sim.params)
     compiled, _ = sim._get_compiled(state, dyn)
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):        # older jax returns one dict per device
-        ca = ca[0]
     return float(ca.get("bytes accessed", -1.0)) / meta["n_ticks"]
 
 

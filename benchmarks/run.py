@@ -169,6 +169,8 @@ def main() -> None:
     args = ap.parse_args()
     skip = set(filter(None, args.skip.split(",")))
     only = set(filter(None, args.only.split(",")))
+    from repro import compile_cache
+    compile_cache.enable()
 
     from . import (bench_capacity, bench_generator, bench_kernels,
                    bench_response, bench_scaling)

@@ -98,7 +98,8 @@ def lint_jaxpr(jaxpr, in_loop: bool = False,
                             f"dtype(s) {wide} — the tick carry is "
                             "f32/i32; a widening here doubles scan "
                             "bandwidth")
-                if "callback" not in waive and "callback" in name \
+                if "callback" not in waive \
+                        and ("callback" in name or name == "debug_print") \
                         and _callback_site(eqn) not in _DECLARED_CALLBACKS:
                     problems.append(
                         f"callback: {name!r} inside the scan body — a "

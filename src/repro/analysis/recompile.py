@@ -41,8 +41,7 @@ def count_backend_compiles() -> Iterator[List[int]]:
     try:
         yield hits
     finally:
-        monitoring._unregister_event_duration_listener_by_callback(
-            _listener)
+        monitoring.unregister_event_duration_listener(_listener)
 
 
 @dataclasses.dataclass

@@ -24,10 +24,7 @@ import datetime as _dt
 import pathlib
 from typing import List, Optional, Sequence, Tuple
 
-try:                      # Python 3.11+
-    import tomllib as _toml
-except ImportError:       # 3.10: vendored tomli is available in-image
-    import tomli as _toml
+import tomllib as _toml
 
 WAIVERS_PATH = pathlib.Path(__file__).with_name("waivers.toml")
 

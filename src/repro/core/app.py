@@ -255,8 +255,9 @@ def build_app(graph: ServiceGraph,
         e_max = max(len(e) for e in api_entries)
         entry = np.full((A, e_max), -1, dtype=np.int32)
         n_entry = np.zeros((A,), dtype=np.int32)
+        sid = {n: i for i, n in enumerate(graph.names)}
         for a, names in enumerate(api_entries):
-            ids = [graph.service_id(n) for n in names]
+            ids = [sid[n] for n in names]
             entry[a, : len(ids)] = ids
             n_entry[a] = len(ids)
 

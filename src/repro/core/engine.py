@@ -419,6 +419,16 @@ class Simulation:
         return tuple((tuple(x.shape), str(x.dtype))
                      for x in jax.tree_util.tree_leaves(tree))
 
+    @staticmethod
+    def _device_key(state: SimState) -> str:
+        """The device the run's fresh state lives on (``tpu:0``,
+        ``cpu:0``; ``jax.default_device`` steers it).  Part of every
+        compiled-cache key: an executable compiled for one backend is
+        never handed to a run on another, such as a CPU reference run in
+        a process that also drives the chip."""
+        dev, = jax.tree_util.tree_leaves(state)[0].devices()
+        return f"{dev.platform}:{dev.id}"
+
     # every SimParams knob that selects program structure (anything NOT
     # carried by the traced DynParams sweep) — cache keys and run_batch
     # validation both derive from this list.  seed is deliberately absent:
@@ -469,7 +479,7 @@ class Simulation:
     def _get_compiled(self, state: SimState, dyn: DynParams):
         from ..analysis.annotate import checked_mode
         checked = checked_mode()
-        key = (self._static_key(), checked,
+        key = (self._device_key(state), self._static_key(), checked,
                self._shape_key((state, dyn, self.app)))
         hit = Simulation._compiled_cache.get(key)
         if hit is not None:
@@ -544,29 +554,24 @@ class Simulation:
                          wall_time_s=t2 - t1, compile_time_s=compile_s)
 
     # ------------------------------------------------------------------
-    def _get_compiled_batch(self, state: SimState, dyn_b: DynParams,
-                            app_b: AppStatic | None = None):
-        # The scaling cadence decision must live OUTSIDE the vmap: a
-        # traced cond under vmap becomes a select that executes the whole
-        # scaling body every tick for every sweep point.  When the sweep
-        # shares one scale_interval (checked on the concrete values) the
-        # batched program scans ticks at the outer level and conds between
-        # vmapped scaling/plain tick variants; otherwise it falls back to
-        # the per-point cond.
+    def _hoists_scaling(self, dyn_b: DynParams) -> bool:
+        """Whether the batch program takes the scaling cadence out of the
+        vmap.  A traced cond under vmap becomes a select that executes the
+        whole scaling body every tick for every sweep point.  When the
+        sweep shares one scale_interval (checked on the concrete values)
+        the batched program scans ticks at the outer level and conds
+        between vmapped scaling/plain tick variants; otherwise it falls
+        back to the per-point cond."""
         has_scaling = bool(self.params.scaling_policy
                            or self.params.migration_enabled)
         si = np.asarray(dyn_b.scale_interval)
-        hoist = has_scaling and bool((si == si.flat[0]).all())
-        batched_app = app_b is not None
-        app_arg = app_b if batched_app else self.app
-        key = ("batch", hoist, batched_app, self._static_key(),
-               self._shape_key((state, dyn_b, app_arg)))
-        hit = Simulation._compiled_cache.get(key)
-        if hit is not None:
-            return hit, 0.0
-        t0 = _time.perf_counter()
+        return has_scaling and bool((si == si.flat[0]).all())
+
+    def _make_batch_run_fn(self, B: int, hoist: bool,
+                           batched_app: bool) -> Callable:
+        """The ``run_batch`` program over ``B`` sweep points (exposed, like
+        :meth:`_make_run_fn`, so it can be lowered on its own)."""
         n_ticks = self.params.n_ticks
-        B = np.asarray(dyn_b.dt).shape[0]
         # app axis: batched sweeps vmap over (dyn, app); plain sweeps close
         # over the one shared app (in_axes None keeps it unbatched)
         app_ax = 0 if batched_app else None
@@ -609,18 +614,34 @@ class Simulation:
                 # traces come out [T, B]; match the scan-inside-vmap layout
                 return states, jax.tree_util.tree_map(
                     lambda x: jnp.swapaxes(x, 0, 1), traces)
-        else:
-            tick = self._tick
+            return run_fn
 
-            def run_fn(st: SimState, dp_b: DynParams, app: AppStatic):
-                def one(dp: DynParams, app_p: AppStatic):
-                    tick_fn = lambda s, _: tick(s, dp, app_p)
-                    if tel_on:
-                        return telmod.chunked_scan(tick_fn, st, params,
-                                                   n_ticks)
-                    return jax.lax.scan(tick_fn, st, None, length=n_ticks)
-                return jax.vmap(one, in_axes=(0, app_ax))(dp_b, app)
+        tick = self._tick
 
+        def run_fn(st: SimState, dp_b: DynParams, app: AppStatic):
+            def one(dp: DynParams, app_p: AppStatic):
+                tick_fn = lambda s, _: tick(s, dp, app_p)
+                if tel_on:
+                    return telmod.chunked_scan(tick_fn, st, params,
+                                               n_ticks)
+                return jax.lax.scan(tick_fn, st, None, length=n_ticks)
+            return jax.vmap(one, in_axes=(0, app_ax))(dp_b, app)
+
+        return run_fn
+
+    def _get_compiled_batch(self, state: SimState, dyn_b: DynParams,
+                            app_b: AppStatic | None = None):
+        hoist = self._hoists_scaling(dyn_b)
+        batched_app = app_b is not None
+        app_arg = app_b if batched_app else self.app
+        key = ("batch", self._device_key(state), hoist, batched_app,
+               self._static_key(), self._shape_key((state, dyn_b, app_arg)))
+        hit = Simulation._compiled_cache.get(key)
+        if hit is not None:
+            return hit, 0.0
+        t0 = _time.perf_counter()
+        B = np.asarray(dyn_b.dt).shape[0]
+        run_fn = self._make_batch_run_fn(B, hoist, batched_app)
         compiled = jax.jit(run_fn).lower(state, dyn_b, app_arg).compile()
         dt = _time.perf_counter() - t0
         Simulation._compiled_cache[key] = compiled

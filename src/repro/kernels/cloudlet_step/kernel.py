@@ -12,12 +12,12 @@ here the accumulator outputs are *revisited* by every grid step
 cloudlet axis is the grid.  The request-side outputs are seeded from their
 input arrays on the first grid step, then scatter-updated per block.
 
-Scatter note: TPU vector scatter (`.at[].add`/`.at[].max` on a VMEM block)
-is legal but serializes per unique index; instance/service counts (≤ a few
-thousand) keep the accumulators resident in VMEM, and capacity-test shapes
-put ~2⁶ lanes per instance so contention is modest.  The per-request
-arrays ride along whole — for request pools too large for VMEM run the
-jnp path (it is scatter-for-scatter equivalent).
+Scatter note: the Pallas TPU lowering refuses the vector scatter
+(`.at[].add`/`.at[].max` on a VMEM block: "Unimplemented primitive …:
+scatter-add"), so this kernel runs in interpret mode only and ops.py
+raises when the compiled kernel is asked for (ROADMAP Speed 6).  The
+per-request arrays ride along whole, so a rewrite must also move them
+out of VMEM for request pools of case1b's size.
 
 Arbitrary pool sizes are supported: inputs are padded up to the block
 multiple with free slots (status 0 never contributes) and the per-cloudlet

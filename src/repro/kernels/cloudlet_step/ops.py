@@ -1,32 +1,32 @@
-"""Jitted wrappers for the fused cloudlet tick with backend dispatch."""
+"""Jitted wrappers for the fused cloudlet tick with backend dispatch.
+
+The kernel runs in interpret mode only (CPU checks against ``ref``); the
+TPU compiler refuses its scatter-add, so asking for the compiled kernel
+raises (see ``kernels/backend.py``).  Everything else takes the jnp path.
+"""
 from __future__ import annotations
 
-import os
-
-import jax
-
+from .. import backend
 from . import ref
 from .kernel import cloudlet_finish_pallas, cloudlet_step_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _force_interpret() -> bool:
-    """CI hook: REPRO_PALLAS_INTERPRET=1 routes every engine-level call
-    through the Pallas kernel in interpret mode, gating the kernels against
-    their jnp oracles on every push."""
-    return os.environ.get("REPRO_PALLAS_INTERPRET") == "1"
+def _refused() -> NotImplementedError:
+    return backend.refused(
+        "cloudlet_finish_pallas",
+        "scatter-add (the .at[].add/.max updates of VMEM blocks, "
+        "kernel.py:_cloudlet_kernel)", "Speed 6")
 
 
 def cloudlet_step(status, rem, inst, rate, time, dt, n_inst: int,
                   use_pallas: bool | None = None, interpret: bool = False):
     """Advance all executing cloudlets one tick (see ref.py for contract)."""
-    interpret = interpret or _force_interpret()
+    interpret = backend.interpret_mode(interpret)
     if use_pallas is None:
-        use_pallas = _on_tpu()
-    if not (use_pallas or interpret):
+        use_pallas = backend.on_tpu()
+    if not interpret:
+        if use_pallas:
+            raise _refused()
         return ref.cloudlet_step(status, rem, inst, rate, time, dt, n_inst)
     C = status.shape[0]
     return cloudlet_step_pallas(status, rem, inst, rate, time, dt,
@@ -41,19 +41,17 @@ def cloudlet_finish(status, rem, inst, req, arrival, start, depth,
                     ) -> ref.FinishOut:
     """One-pass execution tick + all finish reductions (engine hot path).
 
-    Dispatches to the extended Pallas kernel on TPU (or in interpret mode)
-    and to the stacked-scatter jnp reference otherwise.
+    Runs the extended Pallas kernel in interpret mode and the
+    stacked-scatter jnp reference otherwise; ``use_pallas`` (default: on
+    a TPU backend) without interpret mode raises, since the TPU compiler
+    refuses the kernel.
     """
-    interpret = interpret or _force_interpret()
+    interpret = backend.interpret_mode(interpret)
     if use_pallas is None:
-        use_pallas = _on_tpu()
-    # The kernel keeps the six [R] request arrays resident in VMEM
-    # (revisited every grid step); past ~8 MB of request state fall back
-    # to the jnp path, which is scatter-for-scatter equivalent.
-    R = req_finish.shape[0]
-    if use_pallas and not interpret and 6 * 4 * R > (8 << 20):
-        use_pallas = False
-    if not (use_pallas or interpret):
+        use_pallas = backend.on_tpu()
+    if not interpret:
+        if use_pallas:
+            raise _refused()
         return ref.cloudlet_finish(status, rem, inst, req, arrival,
                                    start, depth, rate, time, dt,
                                    req_finish, req_crit, req_out,

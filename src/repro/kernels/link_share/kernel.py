@@ -9,9 +9,11 @@ runs on a single grid step with whole-array blocks and executes the exact
 float program of ``ref.waterfill`` (same op order) on the loaded values —
 interpret-mode tests assert bit-equality against the jnp oracle.
 
-Pools too large for VMEM take the jnp path in ops.py (identical numerics);
-arbitrary pool sizes are supported by padding the transfer axis with
-inactive lanes (they never occupy a port).
+The Pallas TPU lowering refuses the 1-D gathers of ``ref.waterfill``
+("Only 2D gather is supported"), so this kernel runs in interpret mode
+only and ops.py raises when the compiled kernel is asked for (ROADMAP
+Speed 8).  Arbitrary pool sizes are supported by padding the transfer
+axis with inactive lanes (they never occupy a port).
 """
 from __future__ import annotations
 

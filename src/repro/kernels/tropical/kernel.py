@@ -9,6 +9,11 @@ the arithmetic on the VPU.
 
 Grid: (B, M/bm, N/bn, K/bk), k innermost so the output tile stays resident
 in VMEM across the k sweep (standard revisiting-accumulator pattern).
+
+The x operand enters transposed, [B, K, M], so the rank-1 loop reads row
+kk of both tiles through the refs (a dynamic index on the sublane axis).
+Reading column kk of an [M, K] tile instead is a dynamic lane slice,
+which the TPU compiler refuses.
 """
 from __future__ import annotations
 
@@ -21,22 +26,19 @@ from jax.experimental import pallas as pl
 NEG_INF = float("-inf")
 
 
-def _tropical_kernel(x_ref, a_ref, o_ref, *, bk: int):
+def _tropical_kernel(xt_ref, a_ref, o_ref, *, bk: int):
     """One (bm × bn) output tile; accumulate max over the k-grid axis."""
     @pl.when(pl.program_id(3) == 0)
     def _init():
         o_ref[...] = jnp.full_like(o_ref, NEG_INF)
 
-    x = x_ref[0]          # [bm, bk]
-    a = a_ref[0]          # [bk, bn]
-    acc = o_ref[0]        # [bm, bn]
-
     def body(kk, acc):
         # rank-1 max-plus update: acc = max(acc, x[:, kk] + a[kk, :])
-        return jnp.maximum(acc, x[:, kk][:, None] + a[kk, :][None, :])
+        x_col = xt_ref[0, pl.ds(kk, 1), :].T       # [bm, 1]
+        a_row = a_ref[0, pl.ds(kk, 1), :]          # [1, bn]
+        return jnp.maximum(acc, x_col + a_row)
 
-    acc = jax.lax.fori_loop(0, bk, body, acc)
-    o_ref[0] = acc
+    o_ref[0] = jax.lax.fori_loop(0, bk, body, o_ref[0])
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
@@ -60,10 +62,10 @@ def tropical_matmul_pallas(x: jnp.ndarray, a: jnp.ndarray,
         functools.partial(_tropical_kernel, bk=bk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda b, i, j, k: (b, i, k)),
+            pl.BlockSpec((1, bk, bm), lambda b, i, j, k: (b, k, i)),
             pl.BlockSpec((1, bk, bn), lambda b, i, j, k: (b, k, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda b, i, j, k: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, M, N), x.dtype),
         interpret=interpret,
-    )(x, a)
+    )(jnp.swapaxes(x, 1, 2), a)

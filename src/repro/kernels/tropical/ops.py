@@ -1,24 +1,21 @@
 """Jitted public wrappers for tropical (max-plus) linear algebra.
 
-Dispatch policy: the Pallas kernel runs on TPU backends (or under
-``interpret=True`` for CPU validation); every other path uses the pure-jnp
-oracle in ref.py.  Inputs are padded with -inf to 128-aligned tiles so
-arbitrary service-graph sizes are accepted.
+Dispatch policy: the Pallas kernel runs on TPU backends (or in interpret
+mode for CPU validation, which raises on a TPU; see ``kernels/backend.py``);
+every other path uses the pure-jnp oracle in ref.py.  Inputs are padded
+with -inf to 128-aligned tiles so arbitrary service-graph sizes are
+accepted.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import backend
 from . import ref
 from .kernel import tropical_matmul_pallas
 
 _TILE = 128
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_pow(x: jnp.ndarray, m_to: int, n_to: int) -> jnp.ndarray:
@@ -34,8 +31,9 @@ def tropical_matmul(x: jnp.ndarray, a: jnp.ndarray,
                     use_pallas: bool | None = None,
                     interpret: bool = False) -> jnp.ndarray:
     """(…, N, K) ⊗ (…, K, M) with automatic kernel/ref dispatch."""
+    interpret = backend.interpret_mode(interpret)
     if use_pallas is None:
-        use_pallas = _on_tpu()
+        use_pallas = backend.on_tpu()
     if not use_pallas and not interpret:
         return ref.tropical_matmul(x, a)
 

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import checkify, enable_x64
+from jax.experimental import checkify
 
 from repro.analysis import jaxpr_lint, layout_check, recompile, streams
 from repro.analysis.simcheck import check_streams, run_simcheck
@@ -72,7 +72,7 @@ def test_lint_catches_f64_in_hot_loop():
     def leaky(x):
         return (x.astype(jnp.float64) * 2.0).astype(jnp.float32)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(leaky)(jnp.ones((4,), jnp.float32))
     probs = jaxpr_lint.lint_jaxpr(closed, in_loop=True)
     assert any(p.startswith("f64:") for p in probs)
