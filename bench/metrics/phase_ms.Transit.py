@@ -1,0 +1,6 @@
+"""Device time of the engine's Transit tick phase, ms per tick."""
+from cnsbench.readers import phase_ms_per_tick
+
+
+def read(ctx):
+    return phase_ms_per_tick(ctx, "Transit")
