@@ -1,6 +1,6 @@
-"""Streaming telemetry, sampled tracing & phase profiling (DESIGN.md §9).
+"""Streaming telemetry and sampled tracing (DESIGN.md §9).
 
-Three observability surfaces on the paper's SockShop deployment, all
+Two observability surfaces on the paper's SockShop deployment, all
 opt-in (``telemetry="stream"``) and provably observation-only — the
 golden-matrix digests are bit-identical with telemetry on or off
 (tests/test_obs.py):
@@ -19,20 +19,18 @@ golden-matrix digests are bit-identical with telemetry on or off
    with tolerance ZERO, two independent ways: timestamp identity and a
    float64 max-plus (tropical) closure over the span DAG — the same
    Alg 2 recurrence as ``core/critical_path.py``.
-3. **Per-phase profiling** (``--profile``) — prefix programs built with
-   ``make_tick(stop_after=...)`` attribute wall cost per tick phase and
-   per Disruption *stage* (the table feeding DESIGN.md §7's cost
-   attribution).
+
+Per-phase device time comes from a profiler trace of a benchmark job
+(``bench/run.py --trace 1``), not from this example.
 
     PYTHONPATH=src python examples/telemetry_study.py
-    PYTHONPATH=src python examples/telemetry_study.py --profile
 """
 import argparse
 import dataclasses
 
 from repro.configs import sockshop
 from repro.core import batch_item, summarize
-from repro.obs import export, profile, spans
+from repro.obs import export, spans
 
 
 TEL_KW = dict(telemetry="stream", tel_window_ticks=50, tel_windows=4,
@@ -110,33 +108,15 @@ def trace_study(sim, res) -> None:
                  if show.graph is not None else ""))
 
 
-def profile_study(duration_s: float) -> None:
-    print("\n=== 4. per-phase cost attribution (prefix programs) ===")
-    sim = sockshop.make_sim(
-        n_clients=80, duration_s=duration_s, seed=11,
-        faults="chaos", replicas=2,
-        host_mtbf_s=120.0, host_mttr_s=5.0,
-        retry_timeout_s=3.0, retry_budget=2)
-    print(profile.format_table(profile.phase_breakdown(sim, reps=3),
-                               title="tick phase"))
-    print()
-    print(profile.format_table(profile.disruption_breakdown(sim, reps=3),
-                               title="Disruption stage"))
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--duration", type=float, default=60.0)
     ap.add_argument("--points", type=int, default=3,
                     help="sweep points in the run_batch section")
-    ap.add_argument("--profile", action="store_true",
-                    help="also run the (slower) per-phase profiler")
     args = ap.parse_args()
     sim, res = solo_stream(args.duration)
     batch_stream(args.duration, args.points)
     trace_study(sim, res)
-    if args.profile:
-        profile_study(args.duration)
 
 
 if __name__ == "__main__":

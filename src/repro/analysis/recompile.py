@@ -19,29 +19,10 @@ the counting pass is a cache miss the design says cannot exist.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
-from typing import Iterator, List
+from typing import List
 
-from jax._src import monitoring
-
-COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-
-@contextlib.contextmanager
-def count_backend_compiles() -> Iterator[List[int]]:
-    """Yields a one-cell list accumulating backend-compile events."""
-    hits = [0]
-
-    def _listener(event: str, duration: float, **kw) -> None:
-        if event == COMPILE_EVENT:
-            hits[0] += 1
-
-    monitoring.register_event_duration_secs_listener(_listener)
-    try:
-        yield hits
-    finally:
-        monitoring.unregister_event_duration_listener(_listener)
+from ..obs.hostspans import count_backend_compiles
 
 
 @dataclasses.dataclass

@@ -7,15 +7,17 @@ into a single jitted state transition, and ``Simulation`` wraps
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time as _time
-from typing import Callable, Optional, Tuple
+from typing import Callable, Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..analysis import streams
+from ..obs import hostspans
 from . import faults as faultsmod
 from . import network as netmod
 from . import policies
@@ -29,15 +31,10 @@ from .types import (CL_EXEC, CL_TRANSIT, CL_WAITING, DynParams, INST_ON,
                     SimCaps, SimParams, SimState, TickTrace,
                     validate_alerting, validate_telemetry, zeros_state)
 
-# make_tick's phase sequence — ``stop_after`` prefixes must name one.
-TICK_PHASES = ("Generation", "Disruption", "Transit", "Dispatch",
-               "Execute", "Alerting", "Derive", "Response", "Scaling")
-
 
 def make_tick(caps: SimCaps, params: SimParams,
               has_edges: bool = True, scaling: str = "cond",
-              probe: Optional[Callable[[str], None]] = None,
-              stop_after: Optional[str] = None) -> Callable:
+              probe: Optional[Callable[[str], None]] = None) -> Callable:
     """Build the jit-able tick function (paper event cycle, vectorized).
 
     ``params`` supplies the *static* knobs (policy selectors — they choose
@@ -72,12 +69,6 @@ def make_tick(caps: SimCaps, params: SimParams,
     recording ops (span capture after Execute, window close after
     Trace — repro/obs, DESIGN.md §9); ``"none"`` builds the exact
     pre-observability program (the telemetry buffers are zero-width).
-
-    ``stop_after`` truncates the tick right after the named phase
-    (``"Execute"``, or a Disruption stage like ``"Disruption/respawn"``)
-    and returns a zero trace — the obs profiler's prefix programs
-    (obs/profile.py) difference their walls to attribute per-phase cost.
-    ``None`` (the default) builds the full tick.
     """
     if params.network not in ("uniform", "fabric"):
         raise ValueError(
@@ -97,11 +88,6 @@ def make_tick(caps: SimCaps, params: SimParams,
         from ..obs import telemetry as telmod
     if alerting:
         from ..obs import slo as slomod
-    if stop_after is not None \
-            and stop_after.split("/", 1)[0] not in TICK_PHASES:
-        raise ValueError(
-            f"stop_after must name a tick phase {TICK_PHASES} "
-            f"(optionally 'Disruption/<stage>'), got {stop_after!r}")
 
     # Stream names for the tick's single wide split; positions are the
     # contract (split is NOT prefix-stable), names are the audit labels.
@@ -121,16 +107,6 @@ def make_tick(caps: SimCaps, params: SimParams,
         k_net_g, k_net_d = (keys[5], keys[6]) if network else (None, None)
         state = state._replace(rng=rng)
 
-        def early(st: SimState) -> Tuple[SimState, TickTrace]:
-            # profiler prefix cut: advance the clock, zero the trace
-            i0 = jnp.zeros((), jnp.int32)
-            tr = TickTrace(completed=i0, generated=i0, n_waiting=i0,
-                           n_exec=i0, n_transit=i0,
-                           used_mips=jnp.zeros((), jnp.float32),
-                           active_instances=i0, active_clients=i0)
-            return st._replace(tick=st.tick + 1,
-                               time=st.time + dyn.dt), tr
-
         # --- Generation (paper Alg 1) ---------------------------------
         # Each phase body runs under a jax.named_scope so every eqn in
         # the lowered program carries its tick phase — pure metadata
@@ -143,22 +119,15 @@ def make_tick(caps: SimCaps, params: SimParams,
             state, gen_res = scheduler.gen_spawn(
                 state, app, caps, gen.fired, gen.api, gen.wait_proposal,
                 k_gen2, dyn, params=params, net_rng=k_net_g)
-        if stop_after == "Generation":
-            return early(state)
 
         # --- Disruption (chaos mode: faults, retries, breakers) ----------
         if faults_on:
             if probe:
                 probe("Disruption")
-            stage = (stop_after.split("/", 1)[1]
-                     if stop_after and stop_after.startswith("Disruption/")
-                     else None)
             with jax.named_scope("Disruption"):
                 state = faultsmod.disruption(
                     state, app, caps, params, dyn, keys[-3], keys[-2],
-                    keys[-1] if network else None, stop_after=stage)
-        if stop_after and stop_after.startswith("Disruption"):
-            return early(state)
+                    keys[-1] if network else None)
 
         # --- Transit (fabric mode: NIC fair-share water-filling) --------
         if network:
@@ -166,8 +135,6 @@ def make_tick(caps: SimCaps, params: SimParams,
                 probe("Transit")
             with jax.named_scope("Transit"):
                 state = netmod.transit(state, caps, params, dyn, app)
-        if stop_after == "Transit":
-            return early(state)
 
         # --- Dispatching (waiting → execution, load-balanced) ----------
         if probe:
@@ -175,8 +142,6 @@ def make_tick(caps: SimCaps, params: SimParams,
         with jax.named_scope("Dispatch"):
             state = scheduler.dispatch(state, app, caps, params, dyn, k_lb,
                                        network=network)
-        if stop_after == "Dispatch":
-            return early(state)
 
         # --- Scheduling (time-shared execution + finish) ----------------
         if probe:
@@ -184,8 +149,6 @@ def make_tick(caps: SimCaps, params: SimParams,
         with jax.named_scope("Execute"):
             state, fin_info = scheduler.execute(state, app, caps, params,
                                                 dyn)
-        if stop_after == "Execute":
-            return early(state)
 
         # --- Telemetry: span capture (execute cleared only status/rem/
         # inst, and Derive has not yet respawned over the freed slots) ---
@@ -201,8 +164,6 @@ def make_tick(caps: SimCaps, params: SimParams,
                 probe("Alerting")
             with jax.named_scope("Alerting"):
                 state = slomod.alert_step(state, fin_info, params, dyn, app)
-        if stop_after == "Alerting":
-            return early(state)
 
         # --- Derivative (spawn successors along the service chain) ------
         if has_edges:  # static: edge-free graphs skip the spawn machinery
@@ -211,16 +172,12 @@ def make_tick(caps: SimCaps, params: SimParams,
             with jax.named_scope("Derive"):
                 state = scheduler.derive(state, app, caps, fin_info, k_der,
                                          params=params, net_rng=k_net_d)
-        if stop_after == "Derive":
-            return early(state)
 
         # --- Response (critical-path completion, paper §4.3.2) ----------
         if probe:
             probe("Response")
         with jax.named_scope("Response"):
             state, n_done = scheduler.complete(state, dyn, faults=faults_on)
-        if stop_after == "Response":
-            return early(state)
 
         # --- Scaling & Migration (paper §5) ------------------------------
         if probe:
@@ -242,8 +199,6 @@ def make_tick(caps: SimCaps, params: SimParams,
                         (dyn.scale_interval - 1)
                     state = jax.lax.cond(due, do_scale, lambda st: st,
                                          state)
-        if stop_after == "Scaling":
-            return early(state)
 
         if probe:
             probe("Trace")
@@ -282,6 +237,9 @@ class SimResult:
     trace: TickTrace
     wall_time_s: float
     compile_time_s: float
+    # host seconds of each stage of the call (``sim/init_state``,
+    # ``sim/lookup``, ``sim/dispatch``, ...; obs/hostspans.py)
+    host_s: dict = dataclasses.field(default_factory=dict)
 
     def trace_np(self) -> dict:
         return {k: np.asarray(v) for k, v in self.trace._asdict().items()}
@@ -289,12 +247,13 @@ class SimResult:
 
 def batch_item(result: SimResult, b: int) -> SimResult:
     """Slice one sweep point out of a :meth:`Simulation.run_batch` result
-    (wall/compile times are those of the whole batch)."""
+    (wall, compile and host-stage times are those of the whole batch)."""
     take = lambda x: x[b]
     return SimResult(state=jax.tree_util.tree_map(take, result.state),
                      trace=jax.tree_util.tree_map(take, result.trace),
                      wall_time_s=result.wall_time_s,
-                     compile_time_s=result.compile_time_s)
+                     compile_time_s=result.compile_time_s,
+                     host_s=result.host_s)
 
 
 def stack_dyn(dyns) -> DynParams:
@@ -374,6 +333,8 @@ class Simulation:
                                  else placement_policy)
         self._has_edges = bool(np.asarray(graph.n_succ).sum() > 0)
         self._tick = make_tick(self.caps, self.params, self._has_edges)
+        # the executable the latest run / run_batch call ran
+        self.last_compiled = None
 
     # ------------------------------------------------------------------
     def init_state(self, seed: Optional[int] = None) -> SimState:
@@ -413,6 +374,25 @@ class Simulation:
     # One compiled executable per (static knobs × pytree shapes); swept
     # scalars (dyn) and graph parameterizations (app) are traced arguments.
     _compiled_cache: dict = {}
+
+    # Process-wide counters of the engine's host side (``stats()``):
+    # calls, in-memory program cache hits and misses, the seconds the
+    # misses took (lower + compile or persistent-cache load), and the
+    # backend compiles and persistent-cache hits inside the calls' spans
+    # (eager ops' included).
+    _STATS_ZERO = dict(runs=0, program_cache_hits=0, program_compiles=0,
+                       compile_s=0.0, backend_compiles=0,
+                       persistent_cache_hits=0)
+    _stats: dict = dict(_STATS_ZERO)
+
+    @staticmethod
+    def stats() -> dict:
+        """A copy of the engine's process-wide counters."""
+        return dict(Simulation._stats)
+
+    @staticmethod
+    def reset_stats() -> None:
+        Simulation._stats = dict(Simulation._STATS_ZERO)
 
     @staticmethod
     def _shape_key(tree) -> tuple:
@@ -481,10 +461,28 @@ class Simulation:
         checked = checked_mode()
         key = (self._device_key(state), self._static_key(), checked,
                self._shape_key((state, dyn, self.app)))
+        return self._cached(key, lambda: self._compile(state, dyn, checked))
+
+    @staticmethod
+    def _cached(key: tuple, build: Callable):
+        """(program, compile seconds): the cached program under ``key``,
+        or ``build()``'s, timed inside a ``sim/compile`` span, on a
+        miss."""
+        stats = Simulation._stats
         hit = Simulation._compiled_cache.get(key)
         if hit is not None:
+            stats["program_cache_hits"] += 1
             return hit, 0.0
-        t0 = _time.perf_counter()
+        with hostspans.span("sim/compile"):
+            t0 = _time.perf_counter()
+            compiled = build()
+            dt = _time.perf_counter() - t0
+        stats["program_compiles"] += 1
+        stats["compile_s"] += dt
+        Simulation._compiled_cache[key] = compiled
+        return compiled, dt
+
+    def _compile(self, state: SimState, dyn: DynParams, checked: bool):
         run_fn = self._make_run_fn()
 
         if checked:
@@ -495,18 +493,15 @@ class Simulation:
             from jax.experimental import checkify
             run_fn = checkify.checkify(run_fn,
                                        errors=checkify.user_checks)
-            compiled = jax.jit(run_fn).lower(state, dyn, self.app).compile()
+            return jax.jit(run_fn).lower(state, dyn, self.app).compile()
         else:
             # The input state is consumed: run() builds a fresh one per
             # call, so the [C,*] pool blocks alias the output instead of
             # doubling resident bytes.  (Batch paths can't donate — their
             # [B,...] outputs don't match the unbatched input shapes.)
             # simcheck's jaxpr lint enforces this stays donated.
-            compiled = (jax.jit(run_fn, donate_argnums=0)
-                        .lower(state, dyn, self.app).compile())
-        dt = _time.perf_counter() - t0
-        Simulation._compiled_cache[key] = compiled
-        return compiled, dt
+            return (jax.jit(run_fn, donate_argnums=0)
+                    .lower(state, dyn, self.app).compile())
 
     @staticmethod
     def _unalias(state: SimState) -> SimState:
@@ -528,30 +523,64 @@ class Simulation:
             out.append(x)
         return jax.tree_util.tree_unflatten(treedef, out)
 
-    def run(self, seed: Optional[int] = None) -> SimResult:
-        """Compile (AOT, timed separately) and execute the full scan."""
-        state = self._unalias(self.init_state(seed))
-        dyn = DynParams.from_params(self.params)
-        compiled, compile_s = self._get_compiled(state, dyn)
-        t1 = _time.perf_counter()
-        out = compiled(state, dyn, self.app)
-        from ..analysis.annotate import checked_mode
-        if checked_mode():
-            err, (out_state, trace) = out
-            err.throw()
-        else:
-            out_state, trace = out
-        out_state = jax.block_until_ready(out_state)
-        t2 = _time.perf_counter()
-        if self.params.telemetry == "stream":
-            from ..obs import telemetry as telmod
+    @contextlib.contextmanager
+    def _job(self, name: str, seed: Optional[int]
+             ) -> Iterator[hostspans.Record]:
+        """The outermost host span of one ``run`` / ``run_batch`` call
+        (ids: the process-wide run number and the seed); its compile
+        events go into the counters."""
+        stats = Simulation._stats
+        stats["runs"] += 1
+        rec = None
+        try:
+            with hostspans.span(name, run=stats["runs"], seed=int(
+                    self.params.seed if seed is None else seed)) as rec:
+                yield rec
+        finally:
+            if rec is not None:
+                stats["backend_compiles"] += sum(rec.compiles.values())
+                stats["persistent_cache_hits"] += sum(
+                    rec.cache_hits.values())
+
+    def _drain_telemetry(self, out_state: SimState, dyn: DynParams) -> None:
+        if self.params.telemetry != "stream":
+            return
+        from ..obs import telemetry as telmod
+        with hostspans.span("sim/drain"):
             telmod.drain_to_exporter(out_state, self.params)
             if self.params.alerting == "burn":
                 from ..obs import slo as slomod
                 slomod.drain_to_exporter(out_state, self.params,
                                          tags=np.asarray(dyn.tel_tag))
+
+    def run(self, seed: Optional[int] = None) -> SimResult:
+        """Compile (AOT, timed separately) and execute the full scan."""
+        from ..analysis.annotate import checked_mode
+        with self._job("sim/run", seed) as rec:
+            with hostspans.span("sim/init_state"):
+                state = self.init_state(seed)
+            with hostspans.span("sim/unalias"):
+                state = self._unalias(state)
+            with hostspans.span("sim/dyn_params"):
+                dyn = DynParams.from_params(self.params)
+            with hostspans.span("sim/lookup"):
+                compiled, compile_s = self._get_compiled(state, dyn)
+            self.last_compiled = compiled
+            t1 = _time.perf_counter()
+            with hostspans.span("sim/dispatch"):
+                out = compiled(state, dyn, self.app)
+            with hostspans.span("sim/wait"):
+                if checked_mode():
+                    err, (out_state, trace) = out
+                    err.throw()
+                else:
+                    out_state, trace = out
+                out_state = jax.block_until_ready(out_state)
+            t2 = _time.perf_counter()
+            self._drain_telemetry(out_state, dyn)
         return SimResult(state=out_state, trace=trace,
-                         wall_time_s=t2 - t1, compile_time_s=compile_s)
+                         wall_time_s=t2 - t1, compile_time_s=compile_s,
+                         host_s=dict(rec.seconds))
 
     # ------------------------------------------------------------------
     def _hoists_scaling(self, dyn_b: DynParams) -> bool:
@@ -636,16 +665,13 @@ class Simulation:
         app_arg = app_b if batched_app else self.app
         key = ("batch", self._device_key(state), hoist, batched_app,
                self._static_key(), self._shape_key((state, dyn_b, app_arg)))
-        hit = Simulation._compiled_cache.get(key)
-        if hit is not None:
-            return hit, 0.0
-        t0 = _time.perf_counter()
-        B = np.asarray(dyn_b.dt).shape[0]
-        run_fn = self._make_batch_run_fn(B, hoist, batched_app)
-        compiled = jax.jit(run_fn).lower(state, dyn_b, app_arg).compile()
-        dt = _time.perf_counter() - t0
-        Simulation._compiled_cache[key] = compiled
-        return compiled, dt
+
+        def build():
+            B = np.asarray(dyn_b.dt).shape[0]
+            run_fn = self._make_batch_run_fn(B, hoist, batched_app)
+            return jax.jit(run_fn).lower(state, dyn_b, app_arg).compile()
+
+        return self._cached(key, build)
 
     def _check_static_point(self, p: SimParams, b: int) -> None:
         """A sweep point may only vary the DynParams-traced scalars: the
@@ -686,6 +712,30 @@ class Simulation:
         length/payload models for calibration); the whole sweep still
         compiles and dispatches once, vmapped over (dyn, app).
         """
+        with self._job("sim/run_batch", seed) as rec:
+            with hostspans.span("sim/dyn_params"):
+                dyn_batch, app_b = self._batch_inputs(dyn_batch, apps)
+            with hostspans.span("sim/init_state"):
+                state = self.init_state(seed)
+            with hostspans.span("sim/lookup"):
+                compiled, compile_s = self._get_compiled_batch(
+                    state, dyn_batch, app_b)
+            self.last_compiled = compiled
+            t1 = _time.perf_counter()
+            with hostspans.span("sim/dispatch"):
+                out_state, trace = compiled(
+                    state, dyn_batch, app_b if app_b is not None else self.app)
+            with hostspans.span("sim/wait"):
+                out_state = jax.block_until_ready(out_state)
+            t2 = _time.perf_counter()
+            self._drain_telemetry(out_state, dyn_batch)
+        return SimResult(state=out_state, trace=trace,
+                         wall_time_s=t2 - t1, compile_time_s=compile_s,
+                         host_s=dict(rec.seconds))
+
+    def _batch_inputs(self, dyn_batch, apps):
+        """(batched DynParams, batched AppStatic or None) of a
+        ``run_batch`` call, validated."""
         if not isinstance(dyn_batch, DynParams):
             points = list(dyn_batch)
             for b, d in enumerate(points):
@@ -717,23 +767,7 @@ class Simulation:
             if np.all(tags == 0.0):
                 dyn_batch = dyn_batch._replace(
                     tel_tag=jnp.arange(B, dtype=jnp.float32))
-        state = self.init_state(seed)
-        compiled, compile_s = self._get_compiled_batch(state, dyn_batch,
-                                                       app_b)
-        t1 = _time.perf_counter()
-        out_state, trace = compiled(state, dyn_batch,
-                                    app_b if app_b is not None else self.app)
-        out_state = jax.block_until_ready(out_state)
-        t2 = _time.perf_counter()
-        if self.params.telemetry == "stream":
-            from ..obs import telemetry as telmod
-            telmod.drain_to_exporter(out_state, self.params)
-            if self.params.alerting == "burn":
-                from ..obs import slo as slomod
-                slomod.drain_to_exporter(out_state, self.params,
-                                         tags=np.asarray(dyn_batch.tel_tag))
-        return SimResult(state=out_state, trace=trace,
-                         wall_time_s=t2 - t1, compile_time_s=compile_s)
+        return dyn_batch, app_b
 
     # Convenience accessors -------------------------------------------
     def responses(self, result: SimResult) -> np.ndarray:

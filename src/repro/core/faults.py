@@ -69,29 +69,13 @@ def edge_payload_tables(app: AppStatic):
     return mean, std
 
 
-# ``disruption(stop_after=...)`` stages, in phase order — the profiler
-# (obs/profile.py) differences their prefix walls to attribute the
-# phase's cost (ROADMAP item b).
-DISRUPTION_STAGES = ("schedule", "doom", "respawn", "breaker")
-
-
 def disruption(state: SimState, app: AppStatic, caps: SimCaps,
                params: SimParams, dyn: DynParams, rng: jnp.ndarray,
-               rng_len: jnp.ndarray, rng_net=None,
-               stop_after: str | None = None) -> SimState:
+               rng_len: jnp.ndarray, rng_net=None) -> SimState:
     """One Disruption tick: sample the fault schedule, fail doomed work,
     respawn retries, advance the circuit breakers (all masked tensor ops —
     the pool streams a constant number of times, DESIGN.md §2.2).
-
-    ``stop_after`` truncates after the named stage
-    (:data:`DISRUPTION_STAGES`); each cut writes that stage's outputs
-    into the returned state so XLA cannot dead-code-eliminate the work
-    being timed.  ``None`` (default) runs the full phase.
     """
-    if stop_after is not None and stop_after not in DISRUPTION_STAGES:
-        raise ValueError(
-            f"disruption stop_after must be one of {DISRUPTION_STAGES}, "
-            f"got {stop_after!r}")
     cl, inst, req = state.cloudlets, state.instances, state.requests
     fs, fst = state.fault, state.fstats
     i32, f32 = jnp.int32, jnp.float32
@@ -184,15 +168,6 @@ def disruption(state: SimState, app: AppStatic, caps: SimCaps,
     p_heal = cut & upper & (u_p < _p_mean_time(dyn.zone_partition_mttr_s, dt))
     cut_upper = (cut & upper & ~p_heal) | p_open
     zone_cut_new = (cut_upper | cut_upper.T).astype(i32)
-
-    # fault-schedule outputs, written at every profiler cut so the stage
-    # being timed stays live under DCE
-    sched_fault = fs._replace(
-        host_up=up_new.astype(i32), nic_ok=ok_new.astype(i32),
-        host_slow=slow_new.astype(i32), nic_factor=nic_factor,
-        zone_cut=zone_cut_new)
-    if stop_after == "schedule":
-        return state._replace(fault=sched_fault)
 
     # --- instance transitions -------------------------------------------
     host_safe = jnp.maximum(inst.host, 0)
@@ -300,8 +275,6 @@ def disruption(state: SimState, app: AppStatic, caps: SimCaps,
 
     state = state._replace(cloudlets=cl2, instances=instances, vms=vms,
                            requests=requests)
-    if stop_after == "doom":
-        return state._replace(fault=sched_fault)
 
     # --- respawn retries through the two-scatter spawn path ---------------
     # Every retry descriptor's own slot was just freed and the wave is
@@ -356,9 +329,6 @@ def disruption(state: SimState, app: AppStatic, caps: SimCaps,
     with collide("spawn_request_counts"):
         requests = requests._replace(
             spawned=requests.spawned.at[rds2].add(1, mode="drop"))
-    if stop_after == "respawn":
-        return state._replace(rr=rr, cloudlets=cloudlets,
-                              requests=requests, fault=sched_fault)
 
     # --- circuit-breaker update (per edge, masks only) --------------------
     # Fail-fast failures are excluded from the EMA input: they are caused
@@ -378,12 +348,6 @@ def disruption(state: SimState, app: AppStatic, caps: SimCaps,
     open_until = jnp.where(trip | reopen, t + dyn.cb_cooldown_s,
                            jnp.where(close, 0.0, fs.edge_open_until))
     ema = jnp.where(close, 0.0, ema)   # clean slate after a healthy probe
-    if stop_after == "breaker":
-        fault = sched_fault._replace(edge_open_until=open_until,
-                                     edge_err_ema=ema,
-                                     edge_succ=jnp.zeros_like(succ_e))
-        return state._replace(rr=rr, cloudlets=cloudlets,
-                              requests=requests, fault=fault)
 
     # --- per-replica outlier ejection (breaker-aware LB, §7.1) ------------
     # Same three-state machine as the edge breaker, but per instance and
