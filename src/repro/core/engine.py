@@ -335,11 +335,23 @@ class Simulation:
         self._tick = make_tick(self.caps, self.params, self._has_edges)
         # the executable the latest run / run_batch call ran
         self.last_compiled = None
+        # init_state's programs and run's DynParams (see those methods)
+        self._state_programs: dict = {}
+        self._dyn_cache: dict = {}
 
     # ------------------------------------------------------------------
-    def init_state(self, seed: Optional[int] = None) -> SimState:
-        rng = jax.random.PRNGKey(self.params.seed if seed is None else seed)
-        state = zeros_state(self.caps, self.params, rng, app=self.app)
+    def _default_device_key(self) -> str:
+        """The device a fresh array lands on now (``jax.default_device``
+        steers it), in :meth:`_device_key`'s form."""
+        dev = jax.config.jax_default_device
+        if dev is None or isinstance(dev, str):
+            dev = jax.local_devices(backend=dev)[0]
+        return f"{dev.platform}:{dev.id}"
+
+    def _placement(self) -> dict:
+        """The seed-independent part of the initial state, by state group
+        and leaf: Algorithm 3's placement (paper §5.1) with the VMs' usage
+        sums, and the VM and host tables.  Small host NumPy tables."""
         inst, iof, reps = initial_allocation(
             np.asarray(self.app.tmpl_replicas),
             np.asarray(self.app.tmpl_mips),
@@ -349,26 +361,64 @@ class Simulation:
             np.asarray(self.app.tmpl_bw),
             self.vm_mips, self.vm_ram, self.caps,
             policy=self.placement_policy)
-        instances = state.instances._replace(
-            **{k: jnp.asarray(v) for k, v in inst.items()})
+        placed = inst["vm"] >= 0
         vm_used_m = np.zeros_like(self.vm_mips)
         vm_used_r = np.zeros_like(self.vm_ram)
-        for i in range(self.caps.max_instances):
-            v = inst["vm"][i]
-            if v >= 0:
-                vm_used_m[v] += inst["mips"][i]
-                vm_used_r[v] += inst["ram"][i]
-        vms = state.vms._replace(
-            mips=jnp.asarray(self.vm_mips), ram=jnp.asarray(self.vm_ram),
-            mips_used=jnp.asarray(vm_used_m), ram_used=jnp.asarray(vm_used_r))
-        sched = state.sched._replace(inst_of_rank=jnp.asarray(iof),
-                                     svc_replicas=jnp.asarray(reps))
-        hosts = state.hosts._replace(
-            egress_scale=jnp.asarray(self.host_egress_scale),
-            ingress_scale=jnp.asarray(self.host_ingress_scale),
-            cpu_scale=jnp.asarray(self.host_cpu_scale))
-        return state._replace(instances=instances, vms=vms, sched=sched,
-                              hosts=hosts)
+        np.add.at(vm_used_m, inst["vm"][placed], inst["mips"][placed])
+        np.add.at(vm_used_r, inst["vm"][placed], inst["ram"][placed])
+        return dict(
+            instances=inst,
+            vms=dict(mips=self.vm_mips, ram=self.vm_ram,
+                     mips_used=vm_used_m, ram_used=vm_used_r),
+            sched=dict(inst_of_rank=iof, svc_replicas=reps),
+            hosts=dict(egress_scale=self.host_egress_scale,
+                       ingress_scale=self.host_ingress_scale,
+                       cpu_scale=self.host_cpu_scale))
+
+    def init_state(self, seed: Optional[int] = None) -> SimState:
+        """The initial state of a run from ``seed`` (``params.seed`` if
+        None), built on the default device by one compiled program.
+
+        The program traces ``zeros_state`` with :meth:`_placement`'s
+        tables written over it, once per device and program structure.
+        The tables are its constants; the seed is its one argument, in
+        the integer type ``jax.random.PRNGKey`` turns a Python int into,
+        so the key built inside equals the eager ``PRNGKey(seed)`` and a
+        new seed never recompiles.  Every leaf comes back in a buffer of
+        its own, so the state can be donated as it is."""
+        seed = np.int64(self.params.seed if seed is None else seed)
+        seed = seed.astype(jax.dtypes.canonicalize_dtype(np.int64))
+        key = (self._default_device_key(), self._static_key(),
+               self._shape_key(self.app), seed.dtype)
+        stats = Simulation._stats
+        program = self._state_programs.get(key)
+        if program is None:
+            tables = self._placement()
+
+            def init_state(s):
+                state = zeros_state(self.caps, self.params,
+                                    jax.random.PRNGKey(s), app=self.app)
+                return state._replace(**{
+                    group: getattr(state, group)._replace(
+                        **{k: jnp.asarray(v) for k, v in leaves.items()})
+                    for group, leaves in tables.items()})
+
+            program = self._state_programs[key] = jax.jit(init_state)
+            stats["state_compiles"] += 1
+        stats["state_programs"] += 1
+        return program(seed)
+
+    def _dyn_params(self) -> DynParams:
+        """``DynParams.from_params(self.params)``, built once per device
+        and parameter set: the run program donates only the state, so the
+        same buffers serve every job."""
+        key = (self._default_device_key(), self.params)
+        dyn = self._dyn_cache.get(key)
+        if dyn is None:
+            dyn = self._dyn_cache[key] = DynParams.from_params(self.params)
+        else:
+            Simulation._stats["dyn_cache_hits"] += 1
+        return dyn
 
     # ------------------------------------------------------------------
     # One compiled executable per (static knobs × pytree shapes); swept
@@ -379,10 +429,12 @@ class Simulation:
     # calls, in-memory program cache hits and misses, the seconds the
     # misses took (lower + compile or persistent-cache load), and the
     # backend compiles and persistent-cache hits inside the calls' spans
-    # (eager ops' included).
+    # (eager ops' included); the states init_state's program built and
+    # the misses of its cache, and run's DynParams cache hits.
     _STATS_ZERO = dict(runs=0, program_cache_hits=0, program_compiles=0,
                        compile_s=0.0, backend_compiles=0,
-                       persistent_cache_hits=0)
+                       persistent_cache_hits=0, state_programs=0,
+                       state_compiles=0, dyn_cache_hits=0)
     _stats: dict = dict(_STATS_ZERO)
 
     @staticmethod
@@ -506,8 +558,10 @@ class Simulation:
     @staticmethod
     def _unalias(state: SimState) -> SimState:
         """Copy state leaves that share a device buffer with an earlier
-        leaf.  zeros_state's identical constant fills can alias one
-        buffer, and donating the same buffer twice is an XLA error."""
+        leaf: donating the same buffer twice is an XLA error.  The guard
+        of ``run``'s donation; a state from :meth:`init_state`'s program
+        has a buffer per leaf, so it walks the pointers and copies
+        nothing."""
         leaves, treedef = jax.tree_util.tree_flatten(state)
         seen: set = set()
         out = []
@@ -562,7 +616,7 @@ class Simulation:
             with hostspans.span("sim/unalias"):
                 state = self._unalias(state)
             with hostspans.span("sim/dyn_params"):
-                dyn = DynParams.from_params(self.params)
+                dyn = self._dyn_params()
             with hostspans.span("sim/lookup"):
                 compiled, compile_s = self._get_compiled(state, dyn)
             self.last_compiled = compiled
