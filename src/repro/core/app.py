@@ -69,6 +69,11 @@ class AppStatic(NamedTuple):
     #                             fraction, -1 = run-wide default
     #                             (dyn.slo_budget); budget ≤ 0 after
     #                             fallback disables the objective
+    route_p: jnp.ndarray = None  # [A, S, d_max] f32 probability that a
+    #                             request of API a takes successor slot d
+    #                             of service s; None = every API takes
+    #                             every edge, and Derive draws nothing
+    #                             (DESIGN.md §2.5)
 
     @property
     def n_services(self) -> int:
@@ -143,6 +148,17 @@ def validate_app(app: AppStatic, caps) -> None:
             f"total initial replicas {int(reps.sum())} exceed "
             f"caps.max_instances={caps.max_instances}; raise the cap or "
             f"trim the templates")
+
+    if app.route_p is not None:
+        rp = np.asarray(app.route_p)
+        if rp.shape != (A, S, D):
+            problems.append(
+                f"route_p has shape {rp.shape}; it must be [A, S, d_max] = "
+                f"{(A, S, D)}")
+        elif rp.size and (rp.min() < 0 or rp.max() > 1):
+            problems.append(
+                f"route_p holds probabilities outside [0, 1]: "
+                f"[{rp.min()}, {rp.max()}]")
 
     hz = np.asarray(app.host_zone)
     if hz.size and (hz.min() < 0 or hz.max() >= H):
@@ -290,4 +306,6 @@ def build_app(graph: ServiceGraph,
         host_zone=jnp.asarray(hz),
         slo_target_ms=jnp.asarray(slo_t),
         slo_budget=jnp.asarray(slo_b),
+        route_p=(None if graph.route_p is None
+                 else jnp.asarray(graph.route_p)),
     )

@@ -10,6 +10,10 @@ matrix (kernels/tropical — DESIGN.md §2.3):
 
 which equals  max_{p ∈ P} Σ_{n ∈ p} delay(n)  (Eq 5/6) for every chain.
 The critical path itself is recovered by greedy argmax backtracking.
+
+It assumes every request of an API makes every call of the graph, so it
+refuses a graph whose APIs route their calls (per-API call trees or
+calls made with p < 1, DESIGN.md §2.5).
 """
 from __future__ import annotations
 
@@ -23,8 +27,16 @@ from ..kernels.tropical import NEG_INF, tropical_closure
 from .graph import ServiceGraph
 
 
+def _every_call_made(graph: ServiceGraph) -> None:
+    if graph.route_p is not None:
+        raise ValueError(
+            "critical-path analysis covers graphs whose requests make every "
+            "call; this graph routes calls per API or by probability")
+
+
 def delay_matrix(graph: ServiceGraph, delays: np.ndarray) -> jnp.ndarray:
     """A[i, j] = delay(j) on edges of the service DAG, -inf elsewhere."""
+    _every_call_made(graph)
     S = graph.n_services
     adj = graph.adjacency()
     d = np.asarray(delays, dtype=np.float32)
@@ -57,6 +69,7 @@ def response_times_batched(graph: ServiceGraph, delays_bt: np.ndarray,
     This is the fleet-scale shape the tropical kernel is built for:
     [B, S, S] closures in one call.
     """
+    _every_call_made(graph)
     delays_bt = np.asarray(delays_bt, dtype=np.float32)
     B, S = delays_bt.shape
     adj = graph.adjacency()
@@ -79,6 +92,7 @@ def critical_path(graph: ServiceGraph, delays: np.ndarray, api: int
     Longest-path DP in topological order with backtracking — host-side,
     used for reporting and for cross-validating the tropical closure.
     """
+    _every_call_made(graph)
     S = graph.n_services
     d = np.asarray(delays, dtype=np.float64)
     entry = int(graph.api_entry[api])

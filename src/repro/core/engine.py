@@ -332,6 +332,10 @@ class Simulation:
                                  if placement_policy is None
                                  else placement_policy)
         self._has_edges = bool(np.asarray(graph.n_succ).sum() > 0)
+        # a call made with 0 < p < 1: its run's branch counters are read
+        rp = graph.route_p
+        self._conditional = rp is not None and bool(
+            ((rp > 0) & (rp < 1)).any())
         self._tick = make_tick(self.caps, self.params, self._has_edges)
         # the executable the latest run / run_batch call ran
         self.last_compiled = None
@@ -631,10 +635,21 @@ class Simulation:
                     out_state, trace = out
                 out_state = jax.block_until_ready(out_state)
             t2 = _time.perf_counter()
+            self._count_branches(rec, out_state)
             self._drain_telemetry(out_state, dyn)
         return SimResult(state=out_state, trace=trace,
                          wall_time_s=t2 - t1, compile_time_s=compile_s,
                          host_s=dict(rec.seconds))
+
+    def _count_branches(self, rec: hostspans.Record,
+                        out_state: SimState) -> None:
+        """Copy the conditional calls' counters (summed over sweep
+        points) onto the job's record, where the graph has such calls."""
+        if self._conditional:
+            ctr = out_state.counters
+            rec.counts.update(
+                branch_taken=int(np.sum(ctr.branch_taken)),
+                branch_skipped=int(np.sum(ctr.branch_skipped)))
 
     # ------------------------------------------------------------------
     def _hoists_scaling(self, dyn_b: DynParams) -> bool:
@@ -782,6 +797,7 @@ class Simulation:
             with hostspans.span("sim/wait"):
                 out_state = jax.block_until_ready(out_state)
             t2 = _time.perf_counter()
+            self._count_branches(rec, out_state)
             self._drain_telemetry(out_state, dyn_batch)
         return SimResult(state=out_state, trace=trace,
                          wall_time_s=t2 - t1, compile_time_s=compile_s,

@@ -21,7 +21,10 @@ phase between Generation and Transit:
   tick.  A per-edge circuit breaker (error-rate EMA trips open → fail-fast,
   half-open probe after a cooldown) is pure status masks — no control flow
   in the scan.  Exhausted retries propagate to the owning request as a
-  *failed completion*.
+  *failed completion*.  A retry re-sends the call it replaces, on the
+  same edge id: whether a call is made at all (per-API call trees,
+  calls with p < 1, DESIGN.md §2.5) was settled when Derive spawned it,
+  so the per-edge tables keep their meaning under routed graphs.
 * **Feedback** — :class:`FaultStats` (availability, error rate, retry
   amplification, observed MTTR) joins the QoS report; HS scale-out and
   migration place replicas only on up hosts.

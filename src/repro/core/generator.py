@@ -53,9 +53,52 @@ def client_phase(wait: jnp.ndarray, time: jnp.ndarray, req_count: jnp.ndarray,
     # Alg 1 line 13: wait ~ U[p0, p1] (converted to ticks, ≥ 1).
     wait_s = dyn.wait_lo + (dyn.wait_hi - dyn.wait_lo) \
         * jax.random.uniform(k_wait, (Nc,))
-    wait_ticks = jnp.maximum(jnp.round(wait_s / dyn.dt), 1).astype(jnp.int32)
+    wait_ticks = jnp.maximum(jnp.round(divide_rn(wait_s, dyn.dt)),
+                             1).astype(jnp.int32)
     return GenOut(fired=fired, api=api, n_active=n_active,
                   wait_proposal=wait_ticks)
+
+
+def _two_prod(a: jnp.ndarray, b: jnp.ndarray):
+    """(p, e): p = a * b rounded, and p + e = a * b exactly (Dekker's
+    product, with Veltkamp's split of a float32 into two 12-bit
+    halves)."""
+    def halves(v):
+        c = v * jnp.float32(4097.0)
+        hi = c - (c - v)
+        return hi, v - hi
+
+    p = a * b
+    ah, al = halves(a)
+    bh, bl = halves(b)
+    return p, ((ah * bh - p) + ah * bl + al * bh) + al * bl
+
+
+def divide_rn(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
+    """``x / y`` for ``x >= 0``, ``y > 0``, rounded to nearest as IEEE 754
+    divides.
+
+    A TPU's float32 divide can come out one or two units in the last
+    place off (measured on a v5e), and a quotient compared with a
+    threshold (``round`` near a half, a finish test) then goes the other
+    way.  The remainder ``x - q * y`` of a quotient ``q`` one unit off is
+    a float, so it is computed exactly here, and it says whether a
+    neighbour of ``q`` is nearer to ``x / y``; two such steps bring a
+    quotient two units off home.  Where the divide rounds correctly (the
+    CPU), ``q`` itself is returned."""
+    q = x / y
+    return _nearest(x, y, _nearest(x, y, q))
+
+
+def _nearest(x: jnp.ndarray, y: jnp.ndarray, q: jnp.ndarray) -> jnp.ndarray:
+    """Of ``q`` and its two neighbours, the one nearest to ``x / y``
+    (``q`` at most one unit in the last place off)."""
+    p, e = _two_prod(q, y)
+    r = (x - p) - e
+    up = jnp.nextafter(q, jnp.float32(jnp.inf))
+    down = jnp.nextafter(q, jnp.float32(-jnp.inf))
+    return jnp.where(r > (up - q) * y * 0.5, up,
+                     jnp.where(-r > (q - down) * y * 0.5, down, q))
 
 
 # --------------------------------------------------------------------------

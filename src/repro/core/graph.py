@@ -47,6 +47,11 @@ class ServiceGraph:
         seconds (-1 = use the run-wide ``SimParams.retry_timeout_s``) —
         timeout budgets match the per-edge retry budgets, DESIGN.md §7.
     api_timeout : [A] float32 client→entry timeout (-1 = run-wide default).
+    route_p : [A, S, d_max] float32 probability that a request of API ``a``
+        takes successor slot ``d`` out of service ``s`` (0 never, 1 always),
+        or None where every API takes every edge of ``succ`` (DESIGN.md
+        §2.5).  Set only where some API has its own call tree or some edge
+        has p < 1.
     """
 
     names: List[str]
@@ -68,6 +73,7 @@ class ServiceGraph:
     api_retry: np.ndarray = None
     edge_timeout: np.ndarray = None
     api_timeout: np.ndarray = None
+    route_p: np.ndarray = None
 
     def __post_init__(self):
         """Fill default payload/retry tables for graphs built before the
@@ -181,6 +187,9 @@ def build_graph(
     api_retries: Dict[str, int] | None = None,
     timeouts: Dict[Tuple[str, str], float] | None = None,
     api_timeouts: Dict[str, float] | None = None,
+    call_p: Dict[Tuple[str, str], float] | None = None,
+    api_trees: Dict[str, Dict[str, Sequence[Tuple[str, float]]]]
+    | None = None,
 ) -> ServiceGraph:
     """Construct a :class:`ServiceGraph`.
 
@@ -201,6 +210,12 @@ def build_graph(
         unlisted edges fall back to the run-wide
         ``SimParams.retry_timeout_s``, so timeout budgets can match the
         per-edge retry budgets.
+    call_p : (caller, callee) → probability that the call is made (default
+        1); an edge with p < 1 is drawn per call (DESIGN.md §2.5).
+    api_trees : api name → {caller: [(callee, p), ...]}, the API's own call
+        tree, rooted at its entry service.  Its edges join ``succ``; a
+        request of that API takes only them, and an API without a tree
+        takes the services' ``calls`` (with ``call_p``).
     """
     names = list(services)
     index = {n: i for i, n in enumerate(names)}
@@ -213,6 +228,17 @@ def build_graph(
                 raise KeyError(f"unknown service in edge {src}->{dst}")
             succ_lists[index[src]].append(index[dst])
             pred_lists[index[dst]].append(index[src])
+    api_trees = api_trees or {}
+    call_p = call_p or {}
+    for api, tree in api_trees.items():
+        for src, callees in tree.items():
+            for dst, _ in callees:
+                if src not in index or dst not in index:
+                    raise KeyError(f"unknown service in API {api!r} tree "
+                                   f"edge {src}->{dst}")
+                if index[dst] not in succ_lists[index[src]]:
+                    succ_lists[index[src]].append(index[dst])
+                    pred_lists[index[dst]].append(index[src])
 
     obs_out = max([len(l) for l in succ_lists], default=1) or 1
     obs_in = max([len(l) for l in pred_lists], default=1) or 1
@@ -287,6 +313,8 @@ def build_graph(
         [float((api_timeouts or {}).get(a[0], -1.0)) for a in apis],
         np.float32)
 
+    route_p = _route_table(index, succ, apis, calls, call_p, api_trees)
+
     # Topological levels (longest distance from any root).
     levels = np.zeros(S, dtype=np.int32)
     indeg = n_pred.copy()
@@ -309,9 +337,71 @@ def build_graph(
         api_payload_mean=api_payload_mean, api_payload_std=api_payload_std,
         edge_retry=edge_retry, api_retry=api_retry,
         edge_timeout=edge_timeout, api_timeout=api_timeout,
+        route_p=route_p,
     )
     graph.validate()
     return graph
+
+
+def _check_p(p: float, what: str) -> None:
+    if not 0.0 <= p <= 1.0:
+        raise ValueError(f"call probability of {what} is {p}; it must lie "
+                         f"in [0, 1]")
+
+
+def _check_tree(api: str, root: str, tree) -> None:
+    """An API's call tree: rooted at its entry, every caller reached from
+    the root, every service called at most once, so no cycle."""
+    parent: Dict[str, str] = {}
+    for src, callees in tree.items():
+        for dst, p in callees:
+            _check_p(p, f"API {api!r} edge {src}->{dst}")
+            if dst == root or dst in parent:
+                raise ValueError(
+                    f"API {api!r} tree calls {dst!r} twice or calls its "
+                    f"root; an API's calls must form a tree")
+            parent[dst] = src
+    for src in tree:
+        seen, node = set(), src
+        while node != root:
+            if node not in parent or node in seen:
+                raise ValueError(
+                    f"API {api!r} tree lists calls of {src!r}, which its "
+                    f"root {root!r} never reaches")
+            seen.add(node)
+            node = parent[node]
+
+
+def _route_table(index, succ, apis, calls, call_p, api_trees):
+    """[A, S, d_max] probability of each (API, service, successor slot),
+    or None where every API takes every edge with p = 1."""
+    for (src, dst), p in call_p.items():
+        if src not in index or dst not in index \
+                or index[dst] not in succ[index[src]]:
+            raise KeyError(f"call probability declared for non-edge "
+                           f"{src}->{dst}")
+        _check_p(p, f"edge {src}->{dst}")
+    unknown = set(api_trees) - {a[0] for a in apis}
+    if unknown:
+        raise KeyError(f"call trees given for unknown APIs {sorted(unknown)}")
+    if not api_trees and all(p >= 1.0 for p in call_p.values()):
+        return None
+    S, D = succ.shape
+    route = np.zeros((len(apis), S, D), np.float32)
+    default = {(src, dst): call_p.get((src, dst), 1.0)
+               for src, dsts in calls.items() for dst in dsts}
+    for a, (api, root, _) in enumerate(apis):
+        if api in api_trees:
+            _check_tree(api, root, api_trees[api])
+            edges = {(src, dst): p for src, callees in api_trees[api].items()
+                     for dst, p in callees}
+        else:
+            edges = default
+        for (src, dst), p in edges.items():
+            s = index[src]
+            d = int(np.flatnonzero(succ[s] == index[dst])[0])
+            route[a, s, d] = p
+    return route
 
 
 def linear_chain(n: int, mi: float = 1000.0,

@@ -4,7 +4,21 @@ Users describe a cloud-native application with two documents and never
 touch engine internals:
 
 * ``app.json`` — APIs (name, weight, entry service) and services
-  (name, labels, calls, cloudlet length stats), Fig 3a.
+  (name, labels, calls, cloudlet length stats), Fig 3a.  Two extensions
+  route calls (DESIGN.md §2.5):
+
+  - a callee in a ``calls`` list may be ``{"to": name, "p": prob}``, a
+    call made with probability ``p`` (a cache miss, say); a bare name
+    means p = 1;
+  - an API may carry ``"tree": {"root": entry, "calls": {caller:
+    [callee, ...]}}`` in place of ``"entry"``: its own call tree over the
+    services, callees written as in ``calls``.  A request of that API
+    spawns only its tree's calls; an API without a tree takes the
+    services' ``calls``.
+
+  Where neither is used, the engine's Derive spawns every callee, as it
+  always did; the choice is made once, from the graph, when the program
+  is built.
 * ``instances.yaml`` — instance groups (prefix, labels, replicas, size,
   bandwidths, requests/limits), Fig 3b.
 
@@ -57,11 +71,18 @@ def graph_from_spec(spec: Dict[str, Any],
     """
     services = spec["services"]
     names = [s["name"] for s in services]
-    calls = {s["name"]: list(s.get("calls", [])) for s in services}
+    calls, call_p = {}, {}
+    for s in services:
+        edges = [_callee(c) for c in s.get("calls", [])]
+        calls[s["name"]] = [to for to, _ in edges]
+        call_p.update({(s["name"], to): p for to, p in edges if p != 1.0})
+    trees = {a["name"]: {src: [_callee(c) for c in cs]
+                         for src, cs in a["tree"]["calls"].items()}
+             for a in spec["apis"] if "tree" in a}
     len_mean = {s["name"]: float(s.get("mi", default_mi)) for s in services}
     len_std = {s["name"]: float(s.get("mi_std", 0.1 * len_mean[s["name"]]))
                for s in services}
-    apis = [(a["name"], a["entry"], float(a.get("weight", 1.0)))
+    apis = [(a["name"], _entry(a), float(a.get("weight", 1.0)))
             for a in spec["apis"]]
     payloads = {(s["name"], callee): float(mb)
                 for s in services
@@ -84,7 +105,26 @@ def graph_from_spec(spec: Dict[str, Any],
                        retries=retries or None,
                        api_retries=api_retries or None,
                        timeouts=timeouts or None,
-                       api_timeouts=api_timeouts or None)
+                       api_timeouts=api_timeouts or None,
+                       call_p=call_p or None, api_trees=trees or None)
+
+
+def _callee(c) -> tuple:
+    """(callee, p) of one entry of a ``calls`` list."""
+    if isinstance(c, str):
+        return c, 1.0
+    return c["to"], float(c.get("p", 1.0))
+
+
+def _entry(api: Dict[str, Any]) -> str:
+    """An API's entry service: its ``entry``, or its tree's root."""
+    if "tree" not in api:
+        return api["entry"]
+    root = api["tree"]["root"]
+    if api.get("entry", root) != root:
+        raise ValueError(f"API {api['name']!r} enters at {api['entry']!r} "
+                         f"but its tree's root is {root!r}")
+    return root
 
 
 def templates_from_spec(spec: Dict[str, Any],

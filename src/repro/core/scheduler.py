@@ -37,6 +37,7 @@ from . import network as netmod
 from . import policies
 from ..kernels.cloudlet_step import cloudlet_finish_pool as _cloudlet_finish_op
 from .app import AppStatic
+from .generator import divide_rn
 from .pool import (assign_free_slots, scatter_pool, segment_rank,
                    segment_sum as _segsum)
 from ..analysis.annotate import collide
@@ -318,12 +319,6 @@ def execute(state: SimState, app: AppStatic, caps: SimCaps,
     # n_exec is maintained incrementally (dispatch adds admissions, the
     # finish counts below subtract) — no per-tick re-count over the pool.
     n_exec = inst.n_exec
-    if params.share_policy == policies.SHARE_SRPT:
-        w = jnp.where(execm, 1.0 / (rem_c + 1.0), 0.0)
-        wsum = _segsum(w, jnp.where(execm, inst_c, -1), I)
-    else:  # equal time slice: the weight sum IS the execution count
-        w = execm.astype(f32)
-        wsum = n_exec.astype(f32)
     inst_safe = jnp.where(execm, inst_c, 0)
     # Hardware heterogeneity: instances run at their host's CPU speed
     # (hosts.cpu_scale, 1.0 everywhere by default — an exact multiply);
@@ -338,9 +333,17 @@ def execute(state: SimState, app: AppStatic, caps: SimCaps,
         is_slow = (inst.host >= 0) & (state.fault.host_slow[hs] > 0)
         mips_eff = jnp.where(is_slow, mips_eff * dyn.host_slow_factor,
                              mips_eff)
-    rate = jnp.where(execm,
-                     mips_eff[inst_safe] * w
-                     / jnp.maximum(wsum[inst_safe], 1e-9), 0.0)  # MI/s
+    # rounded as IEEE divides on every platform: the finish test below
+    # compares rem with rate * dt exactly (generator.divide_rn)
+    if params.share_policy == policies.SHARE_SRPT:
+        w = jnp.where(execm, 1.0 / (rem_c + 1.0), 0.0)
+        wsum = _segsum(w, jnp.where(execm, inst_c, -1), I)
+        rate = divide_rn(mips_eff[inst_safe] * w,
+                         jnp.maximum(wsum[inst_safe], 1e-9))
+    else:  # equal time slice: one quotient per instance, MIPS over n_exec
+        rate = divide_rn(mips_eff, jnp.maximum(n_exec.astype(f32),
+                                               1e-9))[inst_safe]
+    rate = jnp.where(execm, rate, 0.0)  # MI/s
 
     # --- fused finish reduction: progress + every per-finish aggregate
     # (Pallas kernel on TPU / interpret, stacked-scatter jnp elsewhere);
@@ -472,7 +475,14 @@ def derive(state: SimState, app: AppStatic, caps: SimCaps,
     # pins parent_svc ∈ [0, S-1] for the succ-table row gather below
     parent_svc = jnp.where(info.fin, jnp.maximum(info.pre_service, 0), 0)
     child = app.succ[parent_svc]                      # [C, D]
-    valid = (info.fin[:, None] & (child >= 0)).reshape(-1)
+    valid = info.fin[:, None] & (child >= 0)
+    if app.route_p is not None:  # static: per-API trees or p < 1 calls
+        with jax.named_scope("route"):
+            valid, taken, skipped = _route(valid, app, req.api, info,
+                                           parent_svc, rng)
+        ctr = ctr._replace(branch_taken=ctr.branch_taken + taken,
+                           branch_skipped=ctr.branch_skipped + skipped)
+    valid = valid.reshape(-1)
     svc_flat = child.reshape(-1)
     req_flat = jnp.broadcast_to(info.pre_req[:, None], (C, D)).reshape(-1)
     dep_flat = jnp.broadcast_to((info.pre_depth + 1)[:, None],
@@ -554,6 +564,24 @@ def derive(state: SimState, app: AppStatic, caps: SimCaps,
         dropped_cloudlets=ctr.dropped_cloudlets + asg.n_dropped)
     return state._replace(rr=rr, cloudlets=cloudlets, requests=requests,
                           instances=instances, counters=counters)
+
+
+def _route(valid: jnp.ndarray, app: AppStatic, req_api: jnp.ndarray,
+           info: FinishInfo, parent_svc: jnp.ndarray, rng: jnp.ndarray):
+    """The calls of ``valid`` [C, D] that each finished call's request
+    makes: those its API takes from the parent's service, a conditional
+    one (0 < p < 1) where a uniform draw falls under p.  The draws come
+    from their own stream off the Derive key, so no other draw moves.
+    Returns (calls made, conditional calls made, conditional calls not
+    made)."""
+    api = jnp.where(info.fin, req_api[jnp.maximum(info.pre_req, 0)], 0)
+    p = app.route_p[jnp.maximum(api, 0), parent_svc]          # [C, D]
+    u = jax.random.uniform(streams.fold_in(rng, 1, name="route"), p.shape)
+    made = u < p
+    drawn = valid & (p > 0.0) & (p < 1.0)
+    i32 = jnp.int32
+    return (valid & made, jnp.sum((drawn & made).astype(i32)),
+            jnp.sum((drawn & ~made).astype(i32)))
 
 
 # ===========================================================================

@@ -1010,6 +1010,8 @@ class Counters(NamedTuple):
     scale_in: jnp.ndarray        # i32 HS scale-in events
     scale_up: jnp.ndarray        # i32 VS scale-up events
     scale_down: jnp.ndarray      # i32 VS scale-down events
+    branch_taken: jnp.ndarray    # i32 conditional calls drawn and made
+    branch_skipped: jnp.ndarray  # i32 conditional calls drawn, not made
 
 
 class SimState(NamedTuple):
@@ -1166,7 +1168,7 @@ def zeros_state(caps: SimCaps, params: SimParams, rng, n_services: int = 1,
             wait_sum=jnp.zeros((S,), f32),
         ),
         counters=Counters(*([jnp.zeros((), i32)] * 5 + [jnp.zeros((), f32)]
-                            + [jnp.zeros((), i32)] * 6)),
+                            + [jnp.zeros((), i32)] * 8)),
         fault=FaultState(
             host_up=jnp.ones((V,), i32),
             nic_ok=jnp.ones((V,), i32),

@@ -37,12 +37,14 @@ class Record:
     its direct child spans, by name, in the order they opened (a span
     nested deeper counts inside its parent's time).  ``compiles`` and
     ``cache_hits``: backend compiles and persistent-cache hits by the
-    innermost span open when each arrived."""
+    innermost span open when each arrived.  ``counts``: counters of the
+    job's simulated state that its caller copied out, by name."""
     name: str
     ids: dict
     seconds: dict = dataclasses.field(default_factory=dict)
     compiles: dict = dataclasses.field(default_factory=dict)
     cache_hits: dict = dataclasses.field(default_factory=dict)
+    counts: dict = dataclasses.field(default_factory=dict)
 
 
 _tls = threading.local()          # .stack: [(span name, Record)] open

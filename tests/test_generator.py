@@ -77,3 +77,30 @@ def test_num_limit_respected():
     sim = Simulation(g, caps=caps, params=params)
     res = sim.run()
     assert int(res.state.requests.count) == 100
+
+
+
+@pytest.mark.parametrize("lo,hi,dt", [(0.5, 1.5, 0.01), (5.0, 15.0, 0.1)])
+def test_wait_ticks_divide_rounds_to_nearest(lo, hi, dt):
+    """Every think time the generator can draw (u on its 2^-23 grid)
+    divides by dt as IEEE 754 does, and a quotient one or two units in
+    the last place off, as a TPU's divide can give, is moved back to it
+    by ``divide_rn``'s two steps."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.generator import _nearest, divide_rn
+
+    f32 = np.float32
+    u = np.arange(2 ** 23, dtype=f32) * f32(2.0 ** -23)
+    z = jnp.asarray(f32(lo) + (f32(hi) - f32(lo)) * u)
+    want = np.asarray(z) / f32(dt)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(divide_rn)(z, f32(dt))), want)
+    two_steps = jax.jit(lambda x, y, q: _nearest(x, y, _nearest(x, y, q)))
+    for off in (np.inf, -np.inf):
+        skewed = jnp.asarray(want)
+        for _ in range(2):
+            skewed = jnp.nextafter(skewed, f32(off))
+            np.testing.assert_array_equal(
+                np.asarray(two_steps(z, f32(dt), skewed)), want)
