@@ -426,6 +426,14 @@ class Interp:
         return [ival(-a.hi, -a.lo, unique=a.unique,
                      filler=a.filler and (-a.filler[1], -a.filler[0]))]
 
+    def p_dot_general(self, eqn, invals, scope):
+        # each output is a sum of n products, n the contracted extent
+        a, b = invals
+        (lc, _), _ = eqn.params["dimension_numbers"]
+        n = int(np.prod([eqn.invars[0].aval.shape[d] for d in lc]))
+        lo, hi = _iv_mul((a.lo, a.hi), (b.lo, b.hi))
+        return [ival(_mx(lo, n), _mx(hi, n))]
+
     def p_div(self, eqn, invals, scope):
         a, b = invals
         if b.lo > 0 or b.hi < 0:
@@ -501,6 +509,11 @@ class Interp:
 
     def p_erf_inv(self, eqn, invals, scope):
         return [ival(-INF, INF)]
+
+    def p_nextafter(self, eqn, invals, scope):
+        # nextafter(x, y) lies between x and y
+        x, y = invals
+        return [ival(min(x.lo, y.lo), max(x.hi, y.hi))]
 
     def p_is_finite(self, eqn, invals, scope):
         return [ival(0, 1)]
@@ -861,6 +874,8 @@ class Interp:
 
     def p_pjit(self, eqn, invals, scope):
         return self.run(eqn.params["jaxpr"], invals, scope)
+
+    p_jit = p_pjit       # the same call primitive, renamed in newer JAX
 
     def p_custom_jvp_call(self, eqn, invals, scope):
         return self.run(eqn.params["call_jaxpr"], invals, scope)

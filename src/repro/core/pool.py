@@ -11,6 +11,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import jax.numpy as jnp
+from jax import lax
 
 from ..analysis.annotate import checked_mode, collide, disjoint
 
@@ -25,6 +26,38 @@ def segment_sum(data: jnp.ndarray, ids: jnp.ndarray, n: int,
     with collide("segment_sum"):
         return jnp.zeros((n,), data.dtype).at[idx].add(
             jnp.where(valid, data, jnp.zeros_like(data)), mode="drop")
+
+
+def rank_select(mask: jnp.ndarray, k: int, block: int = 128) -> jnp.ndarray:
+    """[k] i32: the index of the r-th True lane of ``mask`` at rank r, and
+    0 at ranks past the last — what ``zeros.at[rank].set(arange)`` over
+    the masked lanes gives, without its scatter.
+
+    A TPU scatter costs a step per update lane, which here would be every
+    lane of ``mask``.  Instead the lanes are cut into blocks: a rank's
+    block comes from the blocks' running counts ([k, n/block] compares),
+    and its lane from that block's own running count, read by a one-hot
+    product (small integers, exact in float32).
+    """
+    i32, f32 = jnp.int32, jnp.float32
+    n = mask.shape[0]
+    nb = -(-n // block)
+    m = jnp.pad(mask.astype(i32), (0, nb * block - n)).reshape(nb, block)
+    within = jnp.cumsum(m, axis=1)                       # [nb, block]
+    cnt = within[:, -1]
+    incl = jnp.cumsum(cnt)                               # [nb]
+    r = jnp.arange(k, dtype=i32)
+    before = incl[None, :] <= r[:, None]                 # [k, nb]
+    blk = jnp.sum(before, axis=1, dtype=i32)
+    local = r - jnp.sum(jnp.where(before, cnt[None, :], 0), axis=1,
+                        dtype=i32)
+    onehot = (jnp.arange(nb, dtype=i32)[None, :] == blk[:, None]).astype(f32)
+    rows = jnp.dot(onehot, within.astype(f32),
+                   precision=lax.Precision.HIGHEST).astype(i32)
+    lane = jnp.sum(rows <= local[:, None], axis=1, dtype=i32)
+    # the min is a no-op at live ranks; it keeps the index in [0, n) for
+    # the index verifier
+    return jnp.where(r < incl[-1], jnp.minimum(blk * block + lane, n - 1), 0)
 
 
 class SlotAssignment(NamedTuple):

@@ -31,6 +31,7 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..analysis import streams
 from . import network as netmod
@@ -38,8 +39,8 @@ from . import policies
 from ..kernels.cloudlet_step import cloudlet_finish_pool as _cloudlet_finish_op
 from .app import AppStatic
 from .generator import divide_rn
-from .pool import (assign_free_slots, scatter_pool, segment_rank,
-                   segment_sum as _segsum)
+from .pool import (assign_free_slots, rank_select, scatter_pool,
+                   segment_rank, segment_sum as _segsum)
 from ..analysis.annotate import collide
 from .types import (CL_EXEC, CL_FREE, CL_TRANSIT, CL_WAITING,
                     DynParams, INST_DRAIN, INST_FREE, INST_ON, SimCaps,
@@ -89,24 +90,10 @@ def gen_spawn(state: SimState, app: AppStatic, caps: SimCaps,
         in_budget, wait_proposal,
         jnp.where(fired, 0, jnp.maximum(state.clients.wait - 1, 0)))
 
-    # ---- write accepted requests -------------------------------------
-    # The request pool is append-only, so a fresh slot still holds its
-    # zeros_state values (outstanding=spawned=critical_len=0, response=-1,
-    # finish=0) — only api and arrival need writing.  finish then grows
-    # purely via the execute-phase scatter-max (tfin ≥ arrival always).
-    dst = jnp.where(has_slot, slot, R)
-    requests = req._replace(
-        count=req.count + n_accept,
-        api=req.api.at[dst].set(api, mode="drop"),
-        arrival=req.arrival.at[dst].set(
-            jnp.full((Nc,), 0.0, f32) + state.time, mode="drop"),
-    )
-
     # ---- root cloudlet descriptors [K, E] ------------------------------
-    # Compact accepted clients into rank order.
-    client_of_rank = jnp.zeros((K,), i32).at[
-        jnp.where(has_slot & (rank < K), rank, K)
-    ].set(jnp.arange(Nc, dtype=i32), mode="drop")
+    # Compact accepted clients into rank order (they are the first
+    # n_accept fired clients, so their rank among fired is their rank).
+    client_of_rank = rank_select(has_slot, K)
     ranks = jnp.arange(K, dtype=i32)
     r_live = ranks < n_accept
     api_r = api[client_of_rank]                      # [K]
@@ -162,15 +149,45 @@ def gen_spawn(state: SimState, app: AppStatic, caps: SimCaps,
         arrival=jnp.full((Ka,), 0.0, f32) + state.time, start=-1.0,
         rem_bytes=bytes_new)
 
-    # direct scatter-adds: no [R]-sized temporaries on the spawn path.
-    # A request with several entry cloudlets hits its counters repeatedly —
-    # accumulation is the point.
-    rdst = jnp.where(asg.live, req_new, R)
-    with collide("spawn_request_counts"):
-        requests = requests._replace(
-            outstanding=requests.outstanding.at[rdst].add(1, mode="drop"),
-            spawned=requests.spawned.at[rdst].add(1, mode="drop"),
-        )
+    # ---- write accepted requests -------------------------------------
+    # The request pool is append-only: the r-th accepted request takes slot
+    # req.count + r, so the accepted rows fill [count, count + n_accept)
+    # and every request write is one read-modify-write of the W-row window
+    # [s0, s0 + W) that holds them: W lanes of work however large R is.
+    # A fresh slot still holds its zeros_state values
+    # (outstanding=spawned=critical_len=0, response=-1, finish=0); finish
+    # then grows purely via the execute-phase scatter-max (tfin ≥ arrival).
+    W = min(K, R)
+    s0 = jnp.clip(req.count, 0, R - W)
+    # window row j holds rank j - shift; shift = count - s0 ∈ [0, W] since
+    # count ≤ R (the clip states it for the index verifier)
+    shift = jnp.clip(req.count - s0, 0, W)
+    row_rank = jnp.arange(W, dtype=i32) - shift
+    row_live = (row_rank >= 0) & (row_rank < n_accept)
+
+    def by_row(per_rank):
+        """[K] rank-order values → [W] window rows (0 in front of rank 0)."""
+        padded = jnp.concatenate([jnp.zeros((W,), per_rank.dtype), per_rank])
+        return lax.dynamic_slice(padded, (W - shift,), (W,))
+
+    def rmw(col, fn):
+        win = lax.dynamic_slice(col, (s0,), (W,))
+        return lax.dynamic_update_slice(col, fn(win), (s0,))
+
+    # Root cloudlets each request got: its entries that won a pool slot
+    # (the r-th valid descriptor is assigned iff r < n_assigned).  Ranks
+    # ≥ n_accept have no valid entries, so their rows add 0.
+    got = valid & (jnp.cumsum(valid.astype(i32)) - 1 < asg.n_assigned)
+    n_got = by_row(got.reshape(K, E).sum(axis=1, dtype=i32))
+    api_w = by_row(api_r)
+    t_now = state.time.astype(req.arrival.dtype)
+    requests = req._replace(
+        count=req.count + n_accept,
+        api=rmw(req.api, lambda w: jnp.where(row_live, api_w, w)),
+        arrival=rmw(req.arrival, lambda w: jnp.where(row_live, t_now, w)),
+        outstanding=rmw(req.outstanding, lambda w: w + n_got),
+        spawned=rmw(req.spawned, lambda w: w + n_got),
+    )
     counters = ctr._replace(
         spawned=ctr.spawned + asg.n_assigned,
         dropped_cloudlets=ctr.dropped_cloudlets + asg.n_dropped,
