@@ -39,8 +39,8 @@ def flat_services(n: int, mi: float) -> "ServiceGraph":
 
 
 def build_case(n_requests, n_services, replicas, fanout=1,
-               use_pallas_interpret=False, network=False, faults=False,
-               chaos2=False, telemetry=False, slo=False):
+               network=False, faults=False, chaos2=False, telemetry=False,
+               slo=False):
     """Build a capacity Simulation sized to the Table 2 object counts;
     returns (sim, meta) where meta records the sizing decisions.
 
@@ -141,8 +141,6 @@ def build_case(n_requests, n_services, replicas, fanout=1,
         dt=dt, n_ticks=n_ticks, n_clients=nc,
         spawn_rate=nc / 5.0, wait_lo=2.0, wait_hi=6.0,
         num_limit=n_requests, seed=0,
-        use_pallas_tick=use_pallas_interpret,
-        pallas_interpret=use_pallas_interpret,
         network="fabric" if network else "uniform",
         # ample per-host NICs: the phase runs, the workload doesn't starve
         nic_egress_mbps=10_000.0, nic_ingress_mbps=10_000.0,
@@ -181,13 +179,12 @@ CASES = {
 }
 
 
-def perf_record(tag: str, backend: str = "jnp", scale: float = 1.0,
+def perf_record(tag: str, scale: float = 1.0,
                 network: bool = False, faults: bool = False,
                 chaos2: bool = False, telemetry: bool = False,
                 slo: bool = False) -> dict:
     """One BENCH_perf.json record: wall seconds + ticks/sec for a Table 2
-    case.  ``scale`` shrinks the request count (pallas-interpret runs are
-    orders of magnitude slower than compiled backends).  ``network=True``
+    case.  ``scale`` shrinks the request count.  ``network=True``
     re-runs the case with the fabric's Transit phase on (case tagged
     ``<tag>+net``), ``faults=True`` with the Disruption phase on
     (``<tag>+faults``), ``chaos2=True`` with the full gray-failure
@@ -198,8 +195,6 @@ def perf_record(tag: str, backend: str = "jnp", scale: float = 1.0,
     n_requests, n_services, replicas, cpr, fanout = CASES[tag]
     n_requests = max(int(n_requests * scale), 100)
     sim, meta = build_case(n_requests, n_services, replicas, fanout,
-                           use_pallas_interpret=(backend
-                                                 == "pallas-interpret"),
                            network=network, faults=faults, chaos2=chaos2,
                            telemetry=telemetry, slo=slo)
     res = sim.run()
@@ -207,7 +202,7 @@ def perf_record(tag: str, backend: str = "jnp", scale: float = 1.0,
         + ("+chaos2" if chaos2 else ("+faults" if faults else "")) \
         + ("+slo" if slo else ("+obs" if telemetry else ""))
     return dict(
-        case=tag + suffix, backend=backend, scale=scale,
+        case=tag + suffix, scale=scale,
         requests=int(res.state.requests.count),
         cloudlets=int(res.state.counters.spawned),
         n_services=n_services, n_instances=meta["n_instances"],

@@ -1,13 +1,11 @@
-"""Aggregate benchmark runner — one section per paper table/figure plus
-the kernel micro-benchmarks.  Prints ``name,value,paper_reference,derived``
-CSV rows (see common.emit).
+"""Aggregate benchmark runner — one section per paper table/figure.
+Prints ``name,value,paper_reference,derived`` CSV rows (see common.emit).
 
     PYTHONPATH=src python -m benchmarks.run [--skip capacity,...]
 
 ``--perf-json`` additionally writes the machine-readable perf-trajectory
 file (BENCH_perf.json): wall seconds and ticks/sec for the requested
-Table 2 capacity cases on the jnp path plus a scaled-down
-pallas-interpret case, so the hot-path trend is tracked across PRs, and
+Table 2 capacity cases, so the hot-path trend is tracked across PRs, and
 bytes/tick per mode (default / +net / +faults) from XLA cost_analysis —
 the timing-noise-free footprint metric behind the mode-keyed pool layout
 (DESIGN.md §2.2).
@@ -50,7 +48,7 @@ def write_perf_json(path: str, cases, repeats: int = 2) -> None:
     for tag in cases:
         best = None
         for _ in range(max(repeats, 1)):
-            rec = bench_capacity.perf_record(tag, backend="jnp")
+            rec = bench_capacity.perf_record(tag)
             if best is None or rec["wall_s"] < best["wall_s"]:
                 best = rec
         records.append(best)
@@ -87,9 +85,8 @@ def write_perf_json(path: str, cases, repeats: int = 2) -> None:
             best, base_wall = None, float("inf")
             for _ in range(max(repeats, 1)):
                 base_wall = min(base_wall, bench_capacity.perf_record(
-                    "case1b", backend="jnp")["wall_s"])
-                rec = bench_capacity.perf_record("case1b", backend="jnp",
-                                                 **kw)
+                    "case1b")["wall_s"])
+                rec = bench_capacity.perf_record("case1b", **kw)
                 if best is None or rec["wall_s"] < best["wall_s"]:
                     best = rec
             best[ratio_key] = round(best["wall_s"] / max(base_wall, 1e-9),
@@ -99,21 +96,15 @@ def write_perf_json(path: str, cases, repeats: int = 2) -> None:
             print(f"# perf case1b+{name}: {best['wall_s']:.2f}s "
                   f"({best[ratio_key]}x of {vs}, interleaved base "
                   f"{base_wall:.2f}s)")
-    # interpret-mode kernel trend on a scaled-down case (interpret is
-    # orders of magnitude slower — the trend matters, not the magnitude)
-    rec = bench_capacity.perf_record("case1a", backend="pallas-interpret",
-                                     scale=0.01)
-    records.append(rec)
-    print(f"# perf case1a/pallas-interpret(x0.01): {rec['wall_s']:.2f}s")
     for rec in records:
         base = baselines.get(rec["case"])
-        if base and rec["backend"] == "jnp" and rec.get("scale", 1.0) == 1.0:
+        if base and rec.get("scale", 1.0) == 1.0:
             rec["speedup_vs_seed"] = round(base / rec["wall_s"], 2)
     # batched-sweep economics (see bench_scaling.sweep8_demo docstring)
     from . import bench_scaling
     ratio, seq_ratio = bench_scaling.sweep8_demo(duration_s=120.0)
     records.append({
-        "case": "sweep8_hs", "backend": "jnp",
+        "case": "sweep8_hs",
         "batch_over_solo": round(ratio, 3),
         "batch_over_sequential": round(seq_ratio, 3),
         "cpu_count": os.cpu_count(),
@@ -158,7 +149,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--skip", default="",
                     help="comma list: capacity,generator,response,scaling,"
-                         "kernels,perf")
+                         "perf")
     ap.add_argument("--only", default="")
     ap.add_argument("--perf-json", default="",
                     help="path for the machine-readable perf records "
@@ -172,14 +163,13 @@ def main() -> None:
     from repro import compile_cache
     compile_cache.enable()
 
-    from . import (bench_capacity, bench_generator, bench_kernels,
-                   bench_response, bench_scaling)
+    from . import (bench_capacity, bench_generator, bench_response,
+                   bench_scaling)
     sections = [
         ("generator", bench_generator.main),   # Fig 9
         ("capacity", bench_capacity.main),     # Table 2
         ("response", bench_response.main),     # Fig 10
         ("scaling", bench_scaling.main),       # Fig 11
-        ("kernels", bench_kernels.main),
     ]
     if args.perf_json:
         cases = [c for c in args.perf_cases.split(",") if c]

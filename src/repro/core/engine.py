@@ -471,9 +471,9 @@ class Simulation:
     # it only feeds init_state's PRNGKey, so seed-only changes reuse the
     # compiled executable.
     _STATIC_FIELDS = ("lb_policy", "share_policy", "scaling_policy",
-                      "migration_enabled", "n_ticks", "use_pallas_tick",
-                      "pallas_interpret", "network", "waterfill_iters",
-                      "net_hist_bin_s", "faults", "egress_shaping",
+                      "migration_enabled", "n_ticks", "network",
+                      "waterfill_iters", "net_hist_bin_s", "faults",
+                      "egress_shaping",
                       "telemetry", "tel_window_ticks", "tel_windows",
                       "tel_span_k", "tel_span_cap", "tel_span_tick_cap",
                       "alerting",

@@ -13,8 +13,8 @@ mode) is a single load-independent latency per RPC hop.  The fabric mode
   destination NIC before it can contend);
 * in-flight transfers sit in the stacked cloudlet pool under ``CL_TRANSIT``
   with ``rem_bytes`` / ``src_host`` columns, and each tick the max-min fair
-  water-filling kernel (``kernels/link_share``) splits every egress and
-  ingress port among its transfers before ``dispatch`` admits the arrivals;
+  water-filling (:func:`link_share`) splits every egress and ingress port
+  among its transfers before ``dispatch`` admits the arrivals;
 * intra-host hops take a loopback fast path (spawned directly into the
   waiting queue — no NIC occupancy, no transit tick).
 
@@ -24,13 +24,13 @@ discipline of DESIGN.md §2.2.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 from . import policies
-from ..kernels.link_share import link_share
 from .app import AppStatic
 from .pool import segment_rank, segment_sum as _segsum
 from .types import (CL_TRANSIT, CL_WAITING, DynParams, INST_ON, SimCaps,
@@ -45,10 +45,118 @@ MBIT_PER_S_TO_MBYTE_PER_S = 1.0 / 8.0
 
 # One-hot accounting matrices ([C, H] / [C, NB]) beat serialized scatters
 # on CPU/TPU only while they fit comfortably in cache; past this element
-# budget the O(C) segment_sum scatter takes over (counts are exact integers
-# and NetStats carries no cross-implementation bit contract, so the switch
-# is value-safe).
+# budget the O(C) scatter takes over.  Counts are small integers (exact in
+# f32 in any order), so the switch is value-safe.
 ONE_HOT_BUDGET = 1 << 22
+
+# A port counts as saturated once its residual capacity falls below this
+# relative tolerance — exact-arithmetic zero crossings land within a few ULP.
+SAT_REL = 1e-5
+
+
+# ===========================================================================
+# Max-min fair NIC bandwidth sharing
+# ===========================================================================
+#
+# Every in-flight transfer ``t`` occupies up to two ports: the egress NIC of
+# its source host (``src[t]``, -1 = external client — no egress constraint)
+# and the ingress NIC of its destination host (``dst[t]``).  The max-min fair
+# allocation is computed by progressive water-filling:
+#
+#   repeat ``iters`` times:
+#     * per-port fair share  s_p = remaining_cap_p / live_transfers_on_p
+#     * global water level   λ   = min over occupied ports of s_p
+#     * every live transfer gains λ; ports drain λ·n_p
+#     * transfers touching a now-saturated port freeze at their current rate
+#
+#   finally, still-live transfers (more bottleneck levels than rounds) take
+#   one conservative fill: min over their ports of the residual fair share —
+#   always capacity-feasible, so the allocation never oversubscribes a link.
+#
+# The recurrence is exact max-min when the scenario has at most ``iters``
+# distinct bottleneck water levels; beyond that it under-allocates only the
+# transfers still live after the last round.
+
+@functools.partial(jax.jit, static_argnames=("iters",))
+def link_share(src, dst, active, cap_e, cap_i, iters: int = 4):
+    """Max-min fair per-transfer rates (MB/s) over host NIC ports.
+
+    Jitted, so a call outside the tick runs compiled as the Transit phase
+    does: eager op-by-op execution rounds FMA-fusable chains differently
+    (~1 ULP).
+
+    Parameters (all jnp arrays)
+    ---------------------------
+    src : [C] i32 source host per transfer (-1 = no egress constraint).
+    dst : [C] i32 destination host per transfer.
+    active : [C] bool transfer is in flight.
+    cap_e / cap_i : [H] f32 egress / ingress port capacities (MB/s).
+    iters : static number of freeze rounds.
+
+    Returns [C] f32 per-transfer rates (MB/s); 0 on inactive transfers.
+    """
+    f32 = jnp.float32
+    H = cap_e.shape[0]
+    inf = jnp.asarray(jnp.inf, f32)
+
+    live = active & (dst >= 0)
+    rate = jnp.zeros(src.shape, f32)
+    rem_e = cap_e.astype(f32)
+    rem_i = cap_i.astype(f32)
+
+    # Port occupancy: one-hot reduction (vectorizes where CPU/TPU scatters
+    # serialize) while [C, H] fits the budget, scatter-add beyond.
+    hosts = jnp.arange(H, dtype=src.dtype)
+    one_hot = src.shape[0] * H <= ONE_HOT_BUDGET
+
+    def occupancy(live):
+        has_src = live & (src >= 0)
+        if one_hot:
+            n_e = jnp.sum(jnp.where(has_src[:, None],
+                                    src[:, None] == hosts[None, :], False)
+                          .astype(f32), axis=0)
+            n_i = jnp.sum(jnp.where(live[:, None],
+                                    dst[:, None] == hosts[None, :], False)
+                          .astype(f32), axis=0)
+        else:
+            eidx = jnp.where(has_src, src, H)
+            iidx = jnp.where(live, dst, H)
+            n_e = jnp.zeros((H + 1,), f32).at[eidx].add(
+                1.0, mode="drop")[:H]
+            n_i = jnp.zeros((H + 1,), f32).at[iidx].add(
+                1.0, mode="drop")[:H]
+        return n_e, n_i
+
+    for _ in range(iters):
+        n_e, n_i = occupancy(live)
+        share_e = rem_e / jnp.maximum(n_e, 1.0)
+        share_i = rem_i / jnp.maximum(n_i, 1.0)
+        lam = jnp.minimum(
+            jnp.min(jnp.where(n_e > 0, share_e, inf)),
+            jnp.min(jnp.where(n_i > 0, share_i, inf)))
+        lam = jnp.where(jnp.isfinite(lam), jnp.maximum(lam, 0.0), 0.0)
+        rate = rate + jnp.where(live, lam, 0.0)
+        rem_e = rem_e - lam * n_e
+        rem_i = rem_i - lam * n_i
+        sat_e = (n_e > 0) & (rem_e <= SAT_REL * cap_e)
+        sat_i = (n_i > 0) & (rem_i <= SAT_REL * cap_i)
+        frozen = ((src >= 0) & sat_e[jnp.maximum(src, 0)]) \
+            | sat_i[jnp.maximum(dst, 0)]
+        live = live & ~frozen
+
+    # Conservative final fill for transfers still live after the rounds.
+    n_e, n_i = occupancy(live)
+    share_e = rem_e / jnp.maximum(n_e, 1.0)
+    share_i = rem_i / jnp.maximum(n_i, 1.0)
+    fill = jnp.minimum(
+        jnp.where(src >= 0, share_e[jnp.maximum(src, 0)], inf),
+        share_i[jnp.maximum(dst, 0)])
+    rate = rate + jnp.where(live, jnp.maximum(fill, 0.0), 0.0)
+
+    # External-client uploads into an uncontended port: rate stays what the
+    # water-filling gave them (ingress-limited); fully uncontended src=-1
+    # transfers with dst<0 never occur (masked inactive above).
+    return jnp.where(active & (dst >= 0), rate, 0.0)
 
 
 def pick_replicas(svc: jnp.ndarray, live: jnp.ndarray, state: SimState,
@@ -164,11 +272,8 @@ def transit(state: SimState, caps: SimCaps, params: SimParams,
                                         hz[jnp.maximum(dst, 0)]] > 0)
             flowing = flowing & ~cut
 
-    rate = link_share(
-        src, dst, flowing, cap_e, cap_i,
-        iters=params.waterfill_iters,
-        use_pallas=None if params.use_pallas_tick else False,
-        interpret=params.pallas_interpret)
+    rate = link_share(src, dst, flowing, cap_e, cap_i,
+                      iters=params.waterfill_iters)
 
     if params.egress_shaping:
         # Per-instance egress shaping (§6 follow-up): an instance's

@@ -20,10 +20,9 @@ and per-service aggregates (DESIGN.md §2).
 One-pass tick discipline (DESIGN.md §2.2): spawn waves write the stacked
 cloudlet pool with two row scatters (``scatter_pool``), and the execution
 phase folds progress plus every finish-side reduction into a single fused
-op (``cloudlet_finish`` — Pallas kernel on TPU, stacked-scatter jnp
-reference elsewhere), so the ``max_cloudlets`` buffer streams through
-memory a constant number of times per tick regardless of how many
-statistics are maintained.
+op (``cloudlet_finish``, one stacked scatter per table), so the
+``max_cloudlets`` buffer streams through memory a constant number of
+times per tick regardless of how many statistics are maintained.
 """
 from __future__ import annotations
 
@@ -36,7 +35,6 @@ from jax import lax
 from ..analysis import streams
 from . import network as netmod
 from . import policies
-from ..kernels.cloudlet_step import cloudlet_finish_pool as _cloudlet_finish_op
 from .app import AppStatic
 from .generator import divide_rn
 from .pool import (assign_free_slots, rank_select, scatter_pool,
@@ -312,6 +310,103 @@ def dispatch(state: SimState, app: AppStatic, caps: SimCaps,
 # Execute: time-shared progress, finish detection, usage history
 # ===========================================================================
 
+class FinishOut(NamedTuple):
+    """Outputs of :func:`cloudlet_finish`."""
+
+    new_rem: jnp.ndarray    # [C] f32
+    fin: jnp.ndarray        # [C] bool
+    tfin: jnp.ndarray       # [C] f32 sub-tick finish timestamp
+    consumed: jnp.ndarray   # [C] f32 MI consumed this tick
+    inst_acc: jnp.ndarray   # [I+1, 5] f32: used MI/s, finish count,
+    #                         sojourn / exec / wait sums (row I = overflow)
+    req_finish: jnp.ndarray  # [R] f32 updated max finish time per request
+    req_crit: jnp.ndarray    # [R] i32 updated max critical depth
+    req_out: jnp.ndarray     # [R] i32 updated outstanding count
+
+
+def cloudlet_finish(status, rem, inst, req, arrival, start, depth,
+                    rate, time, dt, req_finish, req_crit, req_out,
+                    n_inst: int) -> FinishOut:
+    """One pass over the cloudlet axis: progress, sub-tick finishes, and
+    every per-finish aggregate the scheduler needs (paper §4.2).
+
+    Per-instance statistics (usage, finish count, sojourn/exec/wait sums)
+    land in ONE stacked [I+1, 5] scatter; per-service stats are derived by
+    the caller over the (tiny) instance→service map; the per-request
+    aggregates (max finish time, max critical depth, outstanding) are
+    updated in place so the request pool is never re-streamed.
+
+    Inputs (all [C] unless noted): status i32 (2 = executing), rem f32
+    (MI), inst i32, req i32, arrival f32, start f32, depth i32, rate f32
+    (MI/s), time scalar, dt scalar, req_finish/req_crit/req_out [R];
+    n_inst static.
+    """
+    f32, i32 = jnp.float32, jnp.int32
+    n_req = req_finish.shape[0]
+    execm = status == CL_EXEC
+    prog = rate * dt
+    fin = execm & (rem <= prog) & (rate > 0)
+    tfin = jnp.where(
+        fin, jnp.clip(time + rem / jnp.maximum(rate, 1e-9), time, time + dt),
+        0.0)
+    consumed = jnp.where(execm, jnp.minimum(prog, rem), 0.0)
+    new_rem = jnp.where(execm, jnp.maximum(rem - prog, 0.0), rem)
+    finf = fin.astype(f32)
+
+    # per-instance: usage + finish count + finish-time statistics in ONE
+    # stacked scatter, so the cloudlet axis is streamed exactly once for
+    # all five statistics
+    started = jnp.maximum(start, arrival)
+    sojourn = jnp.where(fin, tfin - arrival, 0.0)
+    exec_t = jnp.where(fin, tfin - started, 0.0)
+    wait_t = jnp.where(fin, started - arrival, 0.0)
+    iidx = jnp.where(execm & (inst >= 0), inst, n_inst)
+    with collide("inst_acc"):
+        inst_acc = jnp.zeros((n_inst + 1, 5), f32).at[iidx].add(
+            jnp.stack([consumed / dt, finf, sojourn, exec_t, wait_t], axis=1),
+            mode="drop")
+
+    # per-request finish aggregates.  Two static strategies, same results:
+    #  * small request pool (R ≤ C, Table 2 services-dominated cases):
+    #    stack both maxima into one pool-sized scatter, then merge — max
+    #    is associative so the merge is exact, and the merge passes are
+    #    over the small R;
+    #  * large request pool (R > C, requests-dominated cases): update in
+    #    place, so the [R] arrays are never re-streamed.
+    ridx = jnp.where(fin & (req >= 0), req, n_req)
+    with collide("req_finish_acc"):
+        if n_req <= status.shape[0]:
+            critf = jnp.where(fin, (depth + 1).astype(f32), 0.0)
+            mx = jnp.zeros((n_req + 1, 2), f32).at[ridx].max(
+                jnp.stack([tfin, critf], axis=1), mode="drop")
+            req_finish = jnp.maximum(req_finish, mx[:n_req, 0])
+            req_crit = jnp.maximum(req_crit, mx[:n_req, 1].astype(i32))
+        else:
+            req_finish = req_finish.at[ridx].max(tfin, mode="drop")
+            req_crit = req_crit.at[ridx].max(depth + 1, mode="drop")
+        req_out = req_out.at[ridx].add(-fin.astype(i32), mode="drop")
+
+    return FinishOut(new_rem=new_rem, fin=fin, tfin=tfin, consumed=consumed,
+                     inst_acc=inst_acc, req_finish=req_finish,
+                     req_crit=req_crit, req_out=req_out)
+
+
+def cloudlet_finish_pool(cl, rate, time, dt, req_finish, req_crit, req_out,
+                         n_inst: int) -> FinishOut:
+    """:func:`cloudlet_finish` over the stacked cloudlet pool: its input
+    columns are sliced out of the ``[C, NI]``/``[C, NF]`` blocks through
+    the mode-keyed :class:`core.types.PoolLayout` carried by ``cl`` — no
+    hard-coded column positions.  Every mode registers the Execute-phase
+    columns."""
+    L = cl.layout
+    ints, flts = cl.ints, cl.flts
+    return cloudlet_finish(
+        ints[:, L.i("status")], flts[:, L.f("rem")], ints[:, L.i("inst")],
+        ints[:, L.i("req")], flts[:, L.f("arrival")],
+        flts[:, L.f("start")], ints[:, L.i("depth")], rate, time, dt,
+        req_finish, req_crit, req_out, n_inst=n_inst)
+
+
 class FinishInfo(NamedTuple):
     fin: jnp.ndarray       # [C] bool finished this tick
     tfin: jnp.ndarray      # [C] f32 sub-tick finish timestamp
@@ -362,19 +457,13 @@ def execute(state: SimState, app: AppStatic, caps: SimCaps,
                                                1e-9))[inst_safe]
     rate = jnp.where(execm, rate, 0.0)  # MI/s
 
-    # --- fused finish reduction: progress + every per-finish aggregate
-    # (Pallas kernel on TPU / interpret, stacked-scatter jnp elsewhere);
+    # --- fused finish reduction: progress + every per-finish aggregate;
     # the per-request arrays are updated in place, so the (often much
-    # larger) request pool is never re-streamed here.  The pool-level
-    # wrapper slices the kernel's input columns out of the stacked blocks
-    # through the mode-keyed PoolLayout ---
+    # larger) request pool is never re-streamed here ---
     req = state.requests
-    out = _cloudlet_finish_op(
+    out = cloudlet_finish_pool(
         cl, rate, state.time, dt,
-        req.finish, req.critical_len, req.outstanding,
-        n_inst=I,
-        use_pallas=None if params.use_pallas_tick else False,
-        interpret=params.pallas_interpret)
+        req.finish, req.critical_len, req.outstanding, n_inst=I)
     fin, tfin = out.fin, out.tfin
     used_mips = out.inst_acc[:I, 0]
     fin_per_inst = out.inst_acc[:I, 1].astype(i32)

@@ -221,12 +221,6 @@ class SimParams:
     slo_event_cap: int = 256      # alert-transition ring capacity (overflow
                                   # drops are counted exactly)
 
-    # --- backend ---------------------------------------------------------
-    use_pallas_tick: bool = False # fused cloudlet_step TPU kernel for the
-                                  # execution phase (CPU runs the jnp ref)
-    pallas_interpret: bool = False  # force the Pallas kernel in interpret
-                                  # mode (CPU validation / perf tracking)
-
     # --- QoS -------------------------------------------------------------
     slo_ms: float = 1000.0        # SLO threshold on response time (ms)
     mi_per_milicore: float = 0.001  # milicores = used_mips / mi_per_milicore

@@ -6,8 +6,8 @@ Each kernel lives in its own subpackage with three files:
   ref.py    — pure-jnp oracle used for interpret-mode validation
 
 On a CPU the kernels run with ``interpret=True`` (tests sweep shapes and
-dtypes against ref).  backend.py holds the dispatch rules every ops.py
-follows on a TPU: interpret mode raises there, and so does a kernel the
-TPU compiler refuses (tests/test_v5e_compile.py compiles the ones it
-accepts for a described v5e chip).
+dtypes against ref).  ``tropical`` serves the post-run critical-path
+analysis (core/critical_path.py) and is picked on a TPU, where its
+interpret mode raises; ``flash_attention`` and ``ssd_scan`` serve the
+language models (models/).  No kernel runs on the simulator's tick.
 """

@@ -1,21 +1,41 @@
 """Jitted public wrappers for tropical (max-plus) linear algebra.
 
-Dispatch policy: the Pallas kernel runs on TPU backends (or in interpret
-mode for CPU validation, which raises on a TPU; see ``kernels/backend.py``);
-every other path uses the pure-jnp oracle in ref.py.  Inputs are padded
-with -inf to 128-aligned tiles so arbitrary service-graph sizes are
-accepted.
+Dispatch policy: the Pallas kernel runs on TPU backends; every other path
+uses the pure-jnp oracle in ref.py.  Interpret mode (``interpret=True`` or
+``REPRO_PALLAS_INTERPRET=1``) runs the kernel in the host interpreter to
+check it against ref.py on a CPU, and raises on a TPU, where it would not
+run on the chip.  Inputs are padded with -inf to 128-aligned tiles so
+arbitrary service-graph sizes are accepted.
 """
 from __future__ import annotations
 
+import os
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import backend
 from . import ref
 from .kernel import tropical_matmul_pallas
 
 _TILE = 128
+
+
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def interpret_mode(interpret: bool) -> bool:
+    """Whether the kernel runs in interpret mode: the caller's flag or
+    ``REPRO_PALLAS_INTERPRET=1``.  Raises on a TPU backend."""
+    interpret = interpret or os.environ.get("REPRO_PALLAS_INTERPRET") == "1"
+    if interpret and on_tpu():
+        raise RuntimeError(
+            "Pallas interpret mode was requested (interpret=True or "
+            "REPRO_PALLAS_INTERPRET=1) on a TPU backend: the kernel would "
+            "run in the host interpreter, not on the chip. Interpret mode "
+            "is for checking kernels on a CPU.")
+    return interpret
 
 
 def _pad_pow(x: jnp.ndarray, m_to: int, n_to: int) -> jnp.ndarray:
@@ -31,9 +51,9 @@ def tropical_matmul(x: jnp.ndarray, a: jnp.ndarray,
                     use_pallas: bool | None = None,
                     interpret: bool = False) -> jnp.ndarray:
     """(…, N, K) ⊗ (…, K, M) with automatic kernel/ref dispatch."""
-    interpret = backend.interpret_mode(interpret)
+    interpret = interpret_mode(interpret)
     if use_pallas is None:
-        use_pallas = backend.on_tpu()
+        use_pallas = on_tpu()
     if not use_pallas and not interpret:
         return ref.tropical_matmul(x, a)
 

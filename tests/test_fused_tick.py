@@ -4,9 +4,9 @@ Three contracts of the single-pass tick refactor are pinned here:
 
  * the stacked-scatter spawn writer (pool.scatter_pool) bit-matches a
    per-field scatter reference on randomized pools,
- * the extended finish-reduction kernel (interpret mode) bit-matches the
-   single-pass jnp reference, which itself bit-matches a per-field
-   seed-style reference (separate _segsum per statistic),
+ * the engine's single-pass finish reduction (scheduler.cloudlet_finish)
+   bit-matches a per-field seed-style reference (separate _segsum per
+   statistic),
  * prefix-sum segment_rank equals the retired sort-based ranking,
  * Simulation.run_batch equals N independent runs, point for point.
 """
@@ -20,9 +20,8 @@ from repro.core import (InstanceTemplate, SimCaps, SimParams, Simulation,
                         batch_item, diamond, linear_chain, resolve_layout)
 from repro.core.pool import (assign_free_slots, scatter_pool, segment_rank,
                              segment_rank_sorted)
+from repro.core.scheduler import cloudlet_finish
 from repro.core.types import Cloudlets
-from repro.kernels.cloudlet_step import cloudlet_finish_ref
-from repro.kernels.cloudlet_step.kernel import cloudlet_finish_pallas
 
 i32, f32 = jnp.int32, jnp.float32
 
@@ -97,7 +96,7 @@ def test_scatter_pool_bitmatches_per_field(C, M, seed, lname, rng):
 
 
 # ---------------------------------------------------------------------------
-# extended finish-reduction kernel vs single-pass jnp reference
+# single-pass finish reduction vs per-field reference
 # ---------------------------------------------------------------------------
 
 def _mk_finish_pool(r, C, I, R):
@@ -150,28 +149,21 @@ def _per_field_finish_reference(args, I):
             req_out.at[ridx].add(-fin.astype(i32), mode="drop"))
 
 
-@pytest.mark.parametrize("C,I,R,bc", [
-    (256, 8, 32, 64),
-    (1000, 33, 2000, 256),     # C not a bc multiple → padding path; R > C
-    (512, 16, 64, 512),
+@pytest.mark.parametrize("C,I,R", [
+    (256, 8, 32),
+    (1000, 33, 2000),          # R > C: in-place request updates
+    (512, 16, 64),
 ])
-def test_finish_kernel_matches_refs(C, I, R, bc):
+def test_finish_kernel_matches_refs(C, I, R):
     r = np.random.default_rng(C + I)
     args = _mk_finish_pool(r, C, I, R)
     time, dt = 12.5, 0.25
-    got = cloudlet_finish_pallas(*args[:8], time, dt, *args[8:],
-                                 n_inst=I, bc=bc, interpret=True)
-    ref = cloudlet_finish_ref(*args[:8], time, dt, *args[8:], n_inst=I)
+    got = cloudlet_finish(*args[:8], time, dt, *args[8:], n_inst=I)
     want = _per_field_finish_reference(args, I)
-    names = ("new_rem", "fin", "tfin", "consumed", "inst_acc",
-             "req_finish", "req_crit", "req_out")
-    for name, g, rf, w in zip(names, got, ref, want):
-        # kernel vs single-pass jnp reference: bit-exact
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(rf),
-                                      err_msg=f"kernel vs ref: {name}")
-        # single-pass reference vs per-field seed reference: bit-exact
-        np.testing.assert_array_equal(np.asarray(rf), np.asarray(w),
-                                      err_msg=f"ref vs per-field: {name}")
+    for name, g, w in zip(got._fields, got, want):
+        # single-pass reduction vs per-field seed reference: bit-exact
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=f"per-field: {name}")
 
 
 # ---------------------------------------------------------------------------

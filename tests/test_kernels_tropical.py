@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.tropical import ref
+from repro.kernels.tropical import ops, ref
 from repro.kernels.tropical.kernel import tropical_matmul_pallas
 from repro.kernels.tropical.ops import tropical_closure, tropical_matmul
 
@@ -77,3 +77,23 @@ def test_identity_is_neutral(rng):
     got = tropical_matmul_pallas(jnp.asarray(x), jnp.asarray(eye),
                                  interpret=True)
     np.testing.assert_allclose(np.asarray(got)[0], x[0], rtol=1e-6)
+
+
+def test_interpret_env_runs_the_kernel_on_cpu(rng, monkeypatch):
+    """REPRO_PALLAS_INTERPRET=1 routes a default call (no flags) through
+    the kernel in interpret mode on a CPU, and it agrees with ref."""
+    calls = []
+    real = ops.tropical_matmul_pallas
+
+    def spy(x, a, interpret=False):
+        calls.append(interpret)
+        return real(x, a, interpret=interpret)
+
+    monkeypatch.setattr(ops, "tropical_matmul_pallas", spy)
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    x = _rand(rng, (5, 7), np.float32)
+    a = _rand(rng, (7, 3), np.float32)
+    got = tropical_matmul(jnp.asarray(x), jnp.asarray(a))
+    assert calls == [True]
+    want = ref.tropical_matmul(jnp.asarray(x), jnp.asarray(a))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)

@@ -1,12 +1,11 @@
-"""Network fabric (DESIGN.md §6): link_share kernel, transit semantics,
-and the uniform-mode bit-identity guarantee.
+"""Network fabric (DESIGN.md §6): link_share water-filling, transit
+semantics, and the uniform-mode bit-identity guarantee.
 
 Pinned contracts:
 
- * the ``link_share`` Pallas kernel (interpret mode) bit-matches its jnp
-   oracle, and the oracle satisfies the max-min fairness properties on
-   hand-built and randomized port topologies (never oversubscribes a port,
-   exact water levels on small cases);
+ * ``link_share`` satisfies the max-min fairness properties on hand-built
+   and randomized port topologies (never oversubscribes a port, exact
+   water levels on small cases);
  * ``network="uniform"`` builds the exact pre-PR program: run()/run_batch()
    responses, counters and traces are bit-identical to digests captured at
    the commit before the fabric landed;
@@ -25,46 +24,30 @@ import pytest
 from repro.core import (InstanceTemplate, SimCaps, SimParams, Simulation,
                         batch_item, diamond, summarize)
 from repro.core.types import CL_TRANSIT
-from repro.kernels.link_share import link_share_pallas, link_share_ref
+from repro.core.network import link_share
 
 i32, f32 = jnp.int32, jnp.float32
 
 
 # ---------------------------------------------------------------------------
-# link_share: kernel vs oracle, max-min fairness properties
+# link_share: max-min fairness properties
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("C,H,seed,iters", [
-    (64, 4, 0, 4), (300, 7, 1, 4),     # C not a bc multiple → padding path
-    (1024, 16, 2, 8), (8, 2, 3, 1),
+    *(pytest.param(256, 5, seed, 4, id=str(seed)) for seed in range(4)),
+    # pool sizes from 8 to 1,024 transfers, one to eight freeze rounds
+    (64, 4, 0, 4), (300, 7, 1, 4), (1024, 16, 2, 8), (8, 2, 3, 1),
 ])
-def test_link_share_kernel_bitmatches_ref(C, H, seed, iters):
+def test_link_share_never_oversubscribes(C, H, seed, iters):
     r = np.random.default_rng(seed)
-    src = np.asarray(r.integers(-1, H, C), np.int32)
-    dst = np.asarray(r.integers(0, H, C), np.int32)
-    active = r.random(C) < 0.6
-    cap_e = jnp.asarray(r.uniform(1.0, 50.0, H), f32)
-    cap_i = jnp.asarray(r.uniform(1.0, 50.0, H), f32)
-    got = link_share_pallas(jnp.asarray(src), jnp.asarray(dst),
-                            jnp.asarray(active), cap_e, cap_i,
-                            iters=iters, bc=256, interpret=True)
-    want = link_share_ref(jnp.asarray(src), jnp.asarray(dst),
-                          jnp.asarray(active), cap_e, cap_i, iters=iters)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-@pytest.mark.parametrize("seed", range(4))
-def test_link_share_never_oversubscribes(seed):
-    r = np.random.default_rng(seed)
-    C, H = 256, 5
     src = np.asarray(r.integers(-1, H, C), np.int32)
     dst = np.asarray(r.integers(0, H, C), np.int32)
     active = r.random(C) < 0.7
     cap_e = np.asarray(r.uniform(0.5, 20.0, H), np.float32)
     cap_i = np.asarray(r.uniform(0.5, 20.0, H), np.float32)
-    rate = np.asarray(link_share_ref(
+    rate = np.asarray(link_share(
         jnp.asarray(src), jnp.asarray(dst), jnp.asarray(active),
-        jnp.asarray(cap_e), jnp.asarray(cap_i), iters=4))
+        jnp.asarray(cap_e), jnp.asarray(cap_i), iters=iters))
     assert (rate >= 0).all()
     assert (rate[~active] == 0).all()
     for h in range(H):
@@ -72,6 +55,34 @@ def test_link_share_never_oversubscribes(seed):
         used_i = rate[active & (dst == h)].sum()
         assert used_e <= cap_e[h] * (1 + 1e-4), h
         assert used_i <= cap_i[h] * (1 + 1e-4), h
+
+
+@pytest.mark.parametrize("C,H,seed", [(256, 5, 0), (300, 7, 3),
+                                      (1024, 16, 4), (2000, 10, 6)])
+def test_link_share_is_max_min_fair(C, H, seed):
+    """With as many rounds as there can be water levels (one per port),
+    the allocation is max-min fair: every flowing transfer crosses a
+    saturated port on which no transfer gets more than it does."""
+    r = np.random.default_rng(seed)
+    src = np.asarray(r.integers(-1, H, C), np.int32)
+    dst = np.asarray(r.integers(0, H, C), np.int32)
+    active = r.random(C) < 0.7
+    cap_e = np.asarray(r.uniform(0.5, 20.0, H), np.float32)
+    cap_i = np.asarray(r.uniform(0.5, 20.0, H), np.float32)
+    rate = np.asarray(link_share(
+        jnp.asarray(src), jnp.asarray(dst), jnp.asarray(active),
+        jnp.asarray(cap_e), jnp.asarray(cap_i), iters=2 * H))
+    tol = 1e-4
+    on_e = [active & (src == h) for h in range(H)]
+    on_i = [active & (dst == h) for h in range(H)]
+    sat_e = [rate[m].sum() >= cap_e[h] * (1 - tol) for h, m in enumerate(on_e)]
+    sat_i = [rate[m].sum() >= cap_i[h] * (1 - tol) for h, m in enumerate(on_i)]
+    for t in np.flatnonzero(active):
+        top = rate[t] * (1 + tol)
+        by_egress = src[t] >= 0 and sat_e[src[t]] \
+            and rate[on_e[src[t]]].max() <= top
+        by_ingress = sat_i[dst[t]] and rate[on_i[dst[t]]].max() <= top
+        assert by_egress or by_ingress, t
 
 
 def test_link_share_exact_waterfill_small():
@@ -86,8 +97,7 @@ def test_link_share_exact_waterfill_small():
     active = jnp.asarray([True, True])
     cap_e = jnp.asarray([10.0, 100.0, 100.0], f32)
     cap_i = jnp.asarray([100.0, 2.0, 100.0], f32)
-    rate = np.asarray(link_share_ref(src, dst, active, cap_e, cap_i,
-                                     iters=4))
+    rate = np.asarray(link_share(src, dst, active, cap_e, cap_i, iters=4))
     np.testing.assert_allclose(rate, [8.0, 2.0], rtol=1e-5)
 
 
@@ -99,8 +109,7 @@ def test_link_share_client_uploads_share_ingress():
     active = jnp.ones(3, bool)
     cap_e = jnp.asarray([5.0], f32)
     cap_i = jnp.asarray([9.0], f32)
-    rate = np.asarray(link_share_ref(src, dst, active, cap_e, cap_i,
-                                     iters=4))
+    rate = np.asarray(link_share(src, dst, active, cap_e, cap_i, iters=4))
     np.testing.assert_allclose(rate, [3.0, 3.0, 3.0], rtol=1e-5)
 
 
@@ -114,8 +123,8 @@ def test_link_share_many_levels_is_conservative():
     dst = jnp.asarray(list(range(1, H)), i32)
     active = jnp.ones(H - 1, bool)
     cap = np.linspace(1.0, 10.0, H).astype(np.float32)
-    rate = np.asarray(link_share_ref(src, dst, active, jnp.asarray(cap),
-                                     jnp.asarray(cap), iters=2))
+    rate = np.asarray(link_share(src, dst, active, jnp.asarray(cap),
+                                 jnp.asarray(cap), iters=2))
     for h in range(H - 1):
         assert rate[h] <= cap[h] * (1 + 1e-4)
 

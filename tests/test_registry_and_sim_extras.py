@@ -190,21 +190,3 @@ def test_validate_app_rejects_api_without_entry():
             app._replace(api_entry=jnp.full_like(app.api_entry, -1)),
             _TINY_CAPS)
 
-
-def test_engine_kernel_path_matches_ref_path():
-    g = diamond(mi=400.0)
-    caps = SimCaps(n_clients=8, max_requests=512, max_cloudlets=512,
-                   max_instances=8, n_vms=2, d_max=2, max_replicas=2)
-    base = dict(dt=0.05, n_ticks=300, n_clients=6, spawn_rate=4.0,
-                wait_lo=0.5, wait_hi=1.5, seed=11)
-    tmpl = InstanceTemplate(mips=4000.0, limit_mips=8000.0)
-    r_ref = Simulation(g, caps=caps, params=SimParams(**base),
-                       default_template=tmpl).run()
-    r_krn = Simulation(g, caps=caps,
-                       params=SimParams(use_pallas_tick=True, **base),
-                       default_template=tmpl).run()
-    np.testing.assert_allclose(
-        np.asarray(r_ref.state.requests.response),
-        np.asarray(r_krn.state.requests.response), rtol=1e-5, atol=1e-5)
-    assert int(r_ref.state.counters.finished) == \
-        int(r_krn.state.counters.finished)
